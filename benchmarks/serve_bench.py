@@ -69,7 +69,9 @@ import time
 import jax
 
 from repro.core.quantizer import parse_quant_mode
-from repro.launch.serve import add_serve_args, build_server, trace_from_args
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.serve import (add_serve_args, build_server, format_mfu,
+                                trace_from_args)
 from repro.launch.slo import bursty_heavy_tail_trace, parse_slo_spec
 
 DEFAULT_OUT = "BENCH_serve.json"
@@ -509,7 +511,7 @@ def run_bench(args, out_path=None):
     _assert_identical(done_h1, done_hM, f"decode horizon 1/{h_hi}")
     for st in (stat_h1, stat_hM):            # serving-metrics contract
         for key in ("host_syncs", "host_syncs_per_token", "mfu",
-                    "tokens_per_joule", "macs_per_token"):
+                    "macs_per_token"):
             assert key in st, f"stats missing {key!r}"
     sync_ratio = (stat_h1["host_syncs_per_token"]
                   / stat_hM["host_syncs_per_token"]
@@ -542,8 +544,7 @@ def run_bench(args, out_path=None):
           f"{stat_h1['host_syncs_per_token']:.3f} syncs/tok "
           f"({sync_ratio:.1f}x fewer) | {stat_hM['tok_per_s']:.1f} vs "
           f"{stat_h1['tok_per_s']:.1f} tok/s ({multi_ratio:.2f}x) | "
-          f"mfu {stat_hM['mfu']:.2e} | "
-          f"{stat_hM['tokens_per_joule']:.2f} tok/J | mesh "
+          f"mfu {format_mfu(stat_hM['mfu'])} | mesh "
           f"{mesh_identity}")
     payload["multistep"] = {
         "token_identical": True,
@@ -557,7 +558,6 @@ def run_bench(args, out_path=None):
         "decode_rounds": stat_hM.get("decode_rounds", 0),
         "decode_compiles": stat_hM["decode_compiles"],
         "mfu": stat_hM["mfu"],
-        "tokens_per_joule": stat_hM["tokens_per_joule"],
         "mesh_identity": mesh_identity,
         "h1": stat_h1,
         "hM": stat_hM,
@@ -605,8 +605,7 @@ def run():
         derived += (f";horizon{ms['horizon']}_sync_ratio="
                     f"{ms['sync_ratio']:.2f}x"
                     f";horizon_speedup={ms['speedup']:.2f}x"
-                    f";mfu={ms['mfu']:.2e}"
-                    f";tok_per_joule={ms['tokens_per_joule']:.2f}")
+                    f";mfu={format_mfu(ms['mfu'])}")
     return [("serve_bench", us, derived)]
 
 
@@ -617,6 +616,7 @@ def main():
                     help=f"write machine-readable results (default off on "
                          f"the CLI; benchmarks.run writes {DEFAULT_OUT})")
     args = ap.parse_args()
+    use_compile_cache()
     run_bench(args, out_path=args.out)
 
 

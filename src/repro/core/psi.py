@@ -261,6 +261,8 @@ def psi_project_int(w: jnp.ndarray, bits: FormatLike) -> jnp.ndarray:
     """Project integer weights onto the PSI-representable set (what the
     hardware effectively multiplies by)."""
     fmt = get_format(bits)
+    if fmt.exact:      # every in-range integer is its own PSI value
+        return w.astype(jnp.int32)
     tab = jnp.asarray(fmt.value_table())
     return tab[w.astype(jnp.int32) - fmt.w_min]
 

@@ -159,6 +159,58 @@ def _scale_axis(path: str, leaf) -> tuple:
     return (leaf.ndim - 2,)
 
 
+def quantize_leaf(path: str, leaf: Any, bits: Optional[int] = None,
+                  pack: bool = False,
+                  policy: Optional[Dict[str, Optional[int]]] = None) -> Any:
+    """One leaf of :func:`quantize_param_tree` (``path`` is its
+    ``/``-joined tree path; ``policy`` already parsed).  Non-quantizable
+    leaves, and leaves the policy keeps in float, pass through."""
+    if not is_quantizable(path, leaf):
+        return leaf
+    leaf_bits = _policy_bits(path, policy, bits)
+    if not leaf_bits:
+        return leaf
+    q = psi.quantize_weights(leaf, leaf_bits, axis=_scale_axis(path, leaf))
+    if (pack and q.fmt.sub_byte and leaf.ndim >= 2
+            and leaf.shape[-2] % 8 == 0 and not re.search(r"embed", path)):
+        return q.pack()
+    return q
+
+
+def check_policy(policy: Optional[Dict[str, Optional[int]]],
+                 params: Dict) -> None:
+    """Warn about policy entries that silently have no effect on ``params``
+    (arrays or shape structs) — exactly the failure mixed precision exists
+    to avoid.  Two loud cases: a key matching no leaf at all (typo), and a
+    *nonzero*-bits key matching only excluded/non-quantizable leaves
+    (contradicted intent — e.g. router=8 when the router is on the exclude
+    list).  A deliberate {"router": 0} keep-float entry stays quiet."""
+    if not policy:
+        return
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    paths = [_path_str(p) for p, _ in leaves]
+    qpaths = [_path_str(p) for p, leaf in leaves
+              if is_quantizable(_path_str(p), leaf)]
+
+    def hit(key, pool):
+        return any(re.search(rf"(^|/)(?:{key})$", p) for p in pool)
+
+    dead = [k for k in policy if k != "default" and not hit(k, paths)]
+    ineffective = [k for k in policy
+                   if k != "default" and policy[k] and k not in dead
+                   and not hit(k, qpaths)]
+    if dead:
+        warnings.warn(
+            f"quantization policy entries matched no parameter leaf: "
+            f"{sorted(dead)} (known weight names: {WEIGHT_NAMES})",
+            stacklevel=3)
+    if ineffective:
+        warnings.warn(
+            f"quantization policy entries match only excluded/"
+            f"non-quantizable leaves and have no effect: "
+            f"{sorted(ineffective)} (see DEFAULT_EXCLUDE)", stacklevel=3)
+
+
 def quantize_param_tree(
     params: Dict,
     bits: Optional[int] = None,
@@ -182,48 +234,10 @@ def quantize_param_tree(
     policy = parse_policy(policy)
     if bits is None and not policy:
         raise ValueError("pass uniform bits= and/or a mixed-precision policy=")
-    paths, qpaths = [], []
-
-    def convert(path, leaf):
-        p = _path_str(path)
-        paths.append(p)
-        if not is_quantizable(p, leaf):
-            return leaf
-        qpaths.append(p)
-        leaf_bits = _policy_bits(p, policy, bits)
-        if not leaf_bits:
-            return leaf
-        q = psi.quantize_weights(leaf, leaf_bits, axis=_scale_axis(p, leaf))
-        if (pack and q.fmt.sub_byte and leaf.ndim >= 2
-                and leaf.shape[-2] % 8 == 0 and not re.search(r"embed", p)):
-            return q.pack()
-        return q
-
-    out = jax.tree_util.tree_map_with_path(convert, params)
-    if policy:
-        # A policy entry that silently has no effect is exactly the failure
-        # mixed precision exists to avoid.  Two loud cases: a key matching
-        # no leaf at all (typo), and a *nonzero*-bits key matching only
-        # excluded/non-quantizable leaves (contradicted intent — e.g.
-        # router=8 when the router is on the exclude list).  A deliberate
-        # {"router": 0} keep-float entry stays quiet.
-        def hit(key, pool):
-            return any(re.search(rf"(^|/)(?:{key})$", p) for p in pool)
-
-        dead = [k for k in policy if k != "default" and not hit(k, paths)]
-        ineffective = [k for k in policy
-                       if k != "default" and policy[k] and k not in dead
-                       and not hit(k, qpaths)]
-        if dead:
-            warnings.warn(
-                f"quantization policy entries matched no parameter leaf: "
-                f"{sorted(dead)} (known weight names: {WEIGHT_NAMES})",
-                stacklevel=2)
-        if ineffective:
-            warnings.warn(
-                f"quantization policy entries match only excluded/"
-                f"non-quantizable leaves and have no effect: "
-                f"{sorted(ineffective)} (see DEFAULT_EXCLUDE)", stacklevel=2)
+    out = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: quantize_leaf(_path_str(path), leaf, bits, pack,
+                                         policy), params)
+    check_policy(policy, params)
     return out
 
 
@@ -265,14 +279,16 @@ def fake_quant_param_tree(params: Dict, bits: int, exclude: Optional[tuple] = No
     latent float weights (paper: networks are *trained with* the quantization).
     """
     exclude = DEFAULT_EXCLUDE if exclude is None else exclude
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: fake_quant_leaf(_path_str(path), leaf, bits),
+        params)
 
-    def convert(path, leaf):
-        p = _path_str(path)
-        if not is_quantizable(p, leaf):
-            return leaf
-        return psi.fake_quant_ste(leaf, bits, _scale_axis(p, leaf))
 
-    return jax.tree_util.tree_map_with_path(convert, params)
+def fake_quant_leaf(path: str, leaf: Any, bits: int) -> Any:
+    """One leaf of :func:`fake_quant_param_tree`."""
+    if not is_quantizable(path, leaf):
+        return leaf
+    return psi.fake_quant_ste(leaf, bits, _scale_axis(path, leaf))
 
 
 def quantized_bytes(params: Dict) -> int:

@@ -42,8 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.psi_matmul import _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -158,7 +156,7 @@ def paged_attention_gather(q, k_pool, v_pool, block_tables, pos,
 # ---------------------------------------------------------------------------
 def _paged_kernel_body(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                        m_ref, l_ref, acc_ref, *, bs, n_bt, n_kv, group,
-                       quantized, ks_ref=None, vs_ref=None):
+                       quantized, ks_ref=None, vs_ref=None, stat_refs=()):
     """One (slot b, table entry j) grid step of the VMEM streaming walk."""
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -217,15 +215,23 @@ def _paged_kernel_body(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         # no visible key at all (inactive slot): exact zero output
         o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
                     ).astype(o_ref.dtype)
+        if stat_refs:                        # (m, l) for a cross-shard merge
+            stat_refs[0][0] = m_ref[...]
+            stat_refs[1][0] = l
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "with_stats"))
 def paged_attention_pallas(q, k_pool, v_pool, block_tables, pos,
-                           k_scale=None, v_scale=None, *, interpret=False):
+                           k_scale=None, v_scale=None, *, interpret=False,
+                           with_stats=False):
     """Flash-decode paged attention: stream pool blocks through VMEM one
     block-table entry at a time.  Same signature/semantics as
     :func:`paged_attention_ref` (to fp32 accumulation-order tolerance;
-    exactly for the masking pattern)."""
+    exactly for the masking pattern).
+
+    ``with_stats=True`` also returns each row's online-softmax running max
+    ``m`` and sum ``l`` ((B, Hq, 1) f32 each), so partial results over
+    disjoint key sets (pool shards) can be merged exactly."""
     B, Hq, D = q.shape
     N, bs, Hkv, _ = k_pool.shape
     n_bt = block_tables.shape[1]
@@ -253,18 +259,30 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, pos,
     body = functools.partial(
         _paged_kernel_body, bs=bs, n_bt=n_bt, n_kv=Hkv, group=group,
         quantized=quantized)
-    if quantized:
-        # scale refs ride after v_ref in the positional operand order
-        def kernel(bt, pp, qr, kr, vr, ksr, vsr, orf, mr, lr, ar):
-            body(bt, pp, qr, kr, vr, orf, mr, lr, ar, ks_ref=ksr, vs_ref=vsr)
-    else:
-        kernel = body
+    n_in = len(operands)
+    n_out = 3 if with_stats else 1
 
+    def kernel(bt, pp, *refs):
+        # positional order: inputs (q, k, v[, k_scale, v_scale]), outputs
+        # (o[, m, l]), then the three scratch buffers
+        ins, outs = refs[:n_in], refs[n_in:n_in + n_out]
+        scales = ins[3:] if quantized else (None, None)
+        body(bt, pp, *ins[:3], outs[0], *refs[n_in + n_out:],
+             ks_ref=scales[0], vs_ref=scales[1], stat_refs=outs[1:])
+
+    o_shape = jax.ShapeDtypeStruct((B, Hq, D), _out_dtype(q, v_pool, v_scale))
+    out_specs = pl.BlockSpec((1, Hq, D), lambda b, j, bt, pp: (b, 0, 0))
+    out_shape = o_shape
+    if with_stats:
+        st_spec = pl.BlockSpec((1, Hq, 1), lambda b, j, bt, pp: (b, 0, 0))
+        st_shape = jax.ShapeDtypeStruct((B, Hq, 1), jnp.float32)
+        out_specs = (out_specs, st_spec, st_spec)
+        out_shape = (o_shape, st_shape, st_shape)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_bt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, j, bt, pp: (b, 0, 0)),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),            # running max m
             pltpu.VMEM((Hq, 1), jnp.float32),            # running sum l
@@ -274,9 +292,8 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, pos,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (B, Hq, D), _out_dtype(q, v_pool, v_scale)),
-        compiler_params=_CompilerParams(
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, pos, *operands)

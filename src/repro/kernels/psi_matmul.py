@@ -68,12 +68,6 @@ def padded_macs(M: int, K: int, N: int, *, bm: int = DEFAULT_BM,
     np_ = -(-N // bn) * bn
     return mp * kp * np_
 
-# jax 0.5 renamed pltpu.TPUCompilerParams -> CompilerParams; accept both so
-# the kernels (and their interpret-mode tests) run across the 0.4/0.5 pin.
-# A future rename fails loudly here at import, not inside pallas_call.
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
 
 def _int8_kernel(x_ref, codes_ref, scale_ref, o_ref, acc_ref, *, k_steps):
     k = pl.program_id(2)
@@ -161,7 +155,7 @@ def psi_matmul_int8(x, codes, scale, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, cp, sp)
@@ -205,7 +199,7 @@ def psi_matmul_packed(x, planes, scale, *, bits, bm=DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, pp, sp)

@@ -63,15 +63,16 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced_config
-from repro.core.quantizer import (fake_quant_param_tree, parse_policy,
-                                  parse_quant_mode, serving_mode_choices)
+from repro.core.quantizer import (parse_policy, parse_quant_mode,
+                                  serving_mode_choices)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.prefix_cache import PrefixCache
 from repro.launch.scheduler import (BlockAllocator, Request, Scheduler,
                                     poisson_trace, replay_round, summarize)
 from repro.launch.slo import parse_slo_spec, slo_report
 from repro.models import build_model, kvcache as kvc
-from repro.perf.roofline_model import PEAK_FLOPS, decode_macs_per_token
+from repro.perf.roofline_model import decode_macs_per_token, device_peaks
 from repro.runtime.executor import Executor
 
 # Prompt lengths are rounded up to a multiple of this before prefill so the
@@ -130,19 +131,17 @@ class Server:
                  n_blocks: Optional[int] = None,
                  speculative: Optional[Tuple[int, int]] = None,
                  prefill_chunk: int = 0, slo=None,
-                 decode_horizon: int = 1, watts: float = 215.0):
+                 decode_horizon: int = 1):
         self.cfg = cfg
         self.paged = cfg.resolved_cache_layout == kvc.PAGED
+        self.final_state = None          # set by each serve()
         # Multi-step decode (DESIGN.md §3 "Multi-step decode & host
         # overlap"): horizon-M rounds of the on-device token loop; 1 = the
-        # classic step-at-a-time path.  ``watts`` is the CLI stand-in board
-        # power for the tokens-per-joule stat (default: a TPU v5e-class
-        # figure, matching the roofline's PEAK_FLOPS denominator).
+        # classic step-at-a-time path.
         self.decode_horizon = int(decode_horizon or 1)
         if self.decode_horizon < 1:
             raise ValueError(
                 f"decode_horizon={decode_horizon} must be >= 1")
-        self.watts = float(watts)
         # Self-speculative decoding (DESIGN.md §"Self-speculative decoding"):
         # (draft_bits, k) or None.  The Executor validates the deep
         # preconditions (paged layout, k <= block_size, quantized params);
@@ -612,7 +611,8 @@ class Server:
                   f"layout paged + ctx serving)")
         return n_shapes
 
-    def _spec_round(self, sched, cache, tok, pos, act, bt, now_fn):
+    def _spec_round(self, sched, cache, tok, pos, act, bt, now_fn,
+                    retire):
         """One self-speculative round (DESIGN.md §"Self-speculative
         decoding"): a fused k-step draft pass at the low-bit view of the
         serving checkpoint, then ONE k-token verify at the target width.
@@ -666,8 +666,7 @@ class Server:
             pos[slot, 0] += n_emit
             if finished:
                 act[slot] = False
-                sched.retire(slot, now)
-                bt[slot, :] = -1
+                retire(slot, now)
             else:
                 tok[slot, 0] = emit[n_emit - 1]
         return cache
@@ -681,6 +680,12 @@ class Server:
         classic batch-synchronous serving over the identical jitted step, so
         benchmark deltas isolate the scheduling policy.  Arrival times are
         interpreted on the wall clock, starting when this call begins.
+
+        Afterwards ``self.final_state`` holds the engine's state: the
+        decode cache as the last step left it and each finished request's
+        block-table row as it stood when the request retired (paged layout;
+        rows are freed at retirement, so a row's blocks may since hold
+        later requests' writes).
         """
         clock = time.perf_counter
         ex = self.executor
@@ -755,6 +760,16 @@ class Server:
         # ``remaining`` input; unused state at horizon 1)
         rem = np.zeros((B,), np.int32)
         bt = (ex.make_block_table() if self.paged else None)
+        kept_rows: Dict[int, np.ndarray] = {}     # -> final_state
+
+        def retire(slot: int, now: float) -> None:
+            """Retire the slot's request and free its table row, keeping a
+            copy of the row for ``final_state``."""
+            if self.paged:
+                kept_rows[sched.running[slot].rid] = bt[slot].copy()
+                bt[slot, :] = -1
+            sched.retire(slot, now)
+
         chunking: Dict[int, int] = {}      # slot -> next piece offset
         steps = 0
         n_chunks = 0
@@ -799,9 +814,7 @@ class Server:
                 rem[slot] = rem_out[slot]
                 if not act_out[slot]:
                     act[slot] = False
-                    sched.retire(slot, now)
-                    if self.paged:
-                        bt[slot, :] = -1
+                    retire(slot, now)
 
         def drain() -> None:
             """Process the in-flight round, if any.  MUST run before any
@@ -824,9 +837,7 @@ class Server:
             carry = None       # host mutated: rebuild from mirrors
             req.emit(first, now)
             if first == self.eos_id or len(req.tokens) >= req.max_new:
-                sched.retire(slot, now)
-                if self.paged:
-                    bt[slot, :] = -1
+                retire(slot, now)
                 return
             tok[slot, 0] = first
             pos[slot, 0] = len(req.prompt) + len(req.tokens) - 1
@@ -958,7 +969,7 @@ class Server:
                     continue   # pressure path preempted every decoder
             if self.spec:
                 cache = self._spec_round(sched, cache, tok, pos, act, bt,
-                                         lambda: clock() - t0)
+                                         lambda: clock() - t0, retire)
                 host_syncs += 2          # draft + verdict materializations
                 steps += 1
                 continue
@@ -998,9 +1009,7 @@ class Server:
                 pos[slot, 0] += 1
                 if t == self.eos_id or len(req.tokens) >= req.max_new:
                     act[slot] = False
-                    sched.retire(slot, now)
-                    if self.paged:
-                        bt[slot, :] = -1
+                    retire(slot, now)
                 else:
                     tok[slot, 0] = t
         drain()      # a trailing all-masked round can still be in flight
@@ -1025,22 +1034,20 @@ class Server:
         stats["decode_horizon"] = self.decode_horizon
         if multi:
             stats["decode_rounds"] = rounds
-        # MFU / tokens-per-joule (the paper's MACs/W figure of merit tied
-        # back to measured throughput; ROADMAP).  MACs/token comes from the
-        # analytic roofline at the mean final context; peak is the
-        # roofline's per-chip constant times the mesh size; energy is the
-        # --watts CLI stand-in (board power), so tokens/J = tok/s / W.
+        # MFU (the paper's MACs/W figure of merit tied back to measured
+        # throughput).  MACs/token comes from the analytic roofline at the
+        # mean final context; peak is the published bf16 peak of the chip
+        # the mesh runs on times the mesh size — None (not measured) when
+        # the mesh is not on a TPU.
         fin = sched.finished
         mean_ctx = (sum(len(r.full_seq) for r in fin) / len(fin)
                     if fin else 1.0)
         macs_tok = decode_macs_per_token(self.cfg, int(mean_ctx))
         n_dev = int(ex.mesh.size)
         stats["macs_per_token"] = round(macs_tok, 1)
-        stats["mfu"] = round(
-            2.0 * macs_tok * stats["tok_per_s"] / (PEAK_FLOPS * n_dev), 8)
-        stats["watts"] = self.watts
-        stats["tokens_per_joule"] = round(
-            stats["tok_per_s"] / self.watts, 4) if self.watts > 0 else 0.0
+        peaks = device_peaks(ex.mesh.devices.flat[0])
+        stats["mfu"] = (None if peaks is None else 2.0 * macs_tok
+                        * stats["tok_per_s"] / (peaks.bf16_flops * n_dev))
         if self.spec:
             rounds = int(sum(r.spec_rounds for r in sched.finished))
             accepted = int(sum(r.spec_accepted for r in sched.finished))
@@ -1089,6 +1096,7 @@ class Server:
                 # must equal n_blocks again (leak check in tests)
                 prefix.drain(blocks)
             stats["blocks_free_end"] = blocks.free_count
+        self.final_state = {"cache": cache, "block_rows": kept_rows}
         return sched.finished, stats
 
     def decode_cache_size(self) -> int:
@@ -1114,23 +1122,24 @@ def build_server(args) -> Tuple[Server, object]:
     cfg.resolved_cache_layout        # validate the layout/family combo early
     cfg.prefix_cache_enabled         # ...and the prefix-cache combo
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # --qat-precondition emulates a checkpoint TRAINED with the quantizer in
+    # the loop (the paper's QAT flow): weights snap to the psi`pre` grid
+    # before the serving quantization.  Random-init weights have logit
+    # margins smaller than low-bit quantization noise, so without this the
+    # speculative draft's acceptance rate is ~0 — a trained checkpoint's
+    # margins are what make self-speculation pay (DESIGN.md).
     pre = int(getattr(args, "qat_precondition", 0) or 0)
-    if pre:
-        # Emulate a checkpoint TRAINED with the quantizer in the loop (the
-        # paper's QAT flow): snap weights to the psi`pre` grid before the
-        # serving quantization.  Random-init weights have logit margins
-        # smaller than low-bit quantization noise, so without this the
-        # speculative draft's acceptance rate is ~0 — a trained checkpoint's
-        # margins are what make self-speculation pay (DESIGN.md).
-        params = fake_quant_param_tree(params, pre)
     policy = parse_policy(getattr(args, "quant_policy", None))
-    if args.quant != "none" or policy:
+    bits = None
+    if args.quant != "none":
         _, bits = parse_quant_mode(args.quant)
-        # pack=True only bit-plane-packs sub-byte leaves, so uniform psi8
-        # stays plain int8 codes while psi5/psi4/... leaves shrink to
-        # fmt.bits/8 bytes per weight.
-        params = model.quantize(params, bits, pack=True, policy=policy)
+    # Built a layer at a time, never as a float tree (Model.init_serving):
+    # pack=True only bit-plane-packs sub-byte leaves, so uniform psi8
+    # stays plain int8 codes while psi5/psi4/... leaves shrink to
+    # fmt.bits/8 bytes per weight.
+    params = model.init_serving(jax.random.PRNGKey(0), bits, pack=True,
+                                policy=policy, precondition=pre)
+    if args.quant != "none" or policy:
         # quant_mode drives the float-leaf (QAT) path only; for serving it
         # records the uniform format (or the policy default) for logging.
         mode = args.quant
@@ -1173,9 +1182,13 @@ def build_server(args) -> Tuple[Server, object]:
                     n_blocks=getattr(args, "cache_blocks", None),
                     speculative=spec, prefill_chunk=chunk, slo=slo,
                     decode_horizon=int(getattr(args, "decode_horizon", 1)
-                                       or 1),
-                    watts=float(getattr(args, "watts", 215.0)))
+                                       or 1))
     return server, cfg
+
+
+def format_mfu(mfu) -> str:
+    """The mfu stat for printing: "not measured" off the chip."""
+    return "not measured" if mfu is None else f"{mfu:.2e}"
 
 
 def trace_from_args(args, cfg):
@@ -1319,10 +1332,6 @@ def add_serve_args(ap: argparse.ArgumentParser) -> None:
                          'device runs the next — ~Mx fewer host syncs per '
                          'token, bit-token-identical to M=1.  Does not '
                          'compose with --speculative (hard error).')
-    ap.add_argument("--watts", type=float, default=215.0,
-                    help="board-power stand-in for the tokens-per-joule "
-                         "stat (default: a TPU v5e-class figure, matching "
-                         "the roofline peak-FLOPs denominator)")
     ap.add_argument("--eos-id", type=int, default=-1,
                     help="-1 disables EOS retirement")
     ap.add_argument("--seed", type=int, default=0)
@@ -1338,6 +1347,7 @@ def main():
     ap.add_argument("--mode", default="continuous",
                     choices=["continuous", "static", "both"])
     args = ap.parse_args()
+    use_compile_cache()
 
     server, cfg = build_server(args)
     modes = (["continuous", "static"] if args.mode == "both"
@@ -1371,8 +1381,7 @@ def main():
         print(f"[{mode}] served {stats['n_requests']} requests: "
               f"{stats['tokens']} tokens in {stats['wall_s']:.3f}s = "
               f"{stats['tok_per_s']:.1f} tok/s | "
-              f"mfu {stats['mfu']:.2e} | "
-              f"{stats['tokens_per_joule']:.2f} tok/J @ {stats['watts']:.0f}W | "
+              f"mfu {format_mfu(stats['mfu'])} | "
               f"latency p50 {stats['p50_latency_s'] * 1e3:.0f}ms "
               f"p99 {stats['p99_latency_s'] * 1e3:.0f}ms | "
               f"ttft p50 {stats['p50_ttft_s'] * 1e3:.0f}ms | "

@@ -26,6 +26,13 @@ from repro.quant import embed, linear, tied_logits
 from repro.runtime import sharding as shr
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_row(stacked, i, row):
+    """``stacked[:, ...][i] = row`` leaf by leaf, in place (donated)."""
+    return jax.tree_util.tree_map(lambda full, r: full.at[i].set(r),
+                                  stacked, row)
+
+
 def _lm_positions(B, S, offset=0):
     return jnp.broadcast_to(jnp.arange(offset, offset + S)[None], (B, S))
 
@@ -35,22 +42,40 @@ class Model:
     cfg: object
 
     # ------------------------------------------------------------------ init
+    @staticmethod
+    def _init_keys(key):
+        """(embed, stack, lm_head, encoder) keys of :meth:`init`."""
+        return jax.random.split(key, 4)
+
     def init(self, key) -> dict:
+        params = self._init_outer(key)
+        params["stack"] = transformer.init_decoder_stack(
+            self.cfg, self._init_keys(key)[1])
+        return params
+
+    def _init_outer(self, key) -> dict:
+        """Every parameter of :meth:`init` except the decoder stack."""
+        return {name: draw() for name, draw in self._outer_draws(key).items()}
+
+    def _outer_draws(self, key) -> dict:
+        """name -> thunk drawing that top-level parameter (all but the
+        decoder stack), so :meth:`init_serving` can draw one at a time."""
         cfg = self.cfg
-        k_e, k_s, k_h, k_enc = jax.random.split(key, 4)
-        params = {
-            "embed": jax.random.normal(k_e, (cfg.vocab_size, cfg.d_model),
-                                       jnp.float32) * cfg.d_model ** -0.5,
-            "stack": transformer.init_decoder_stack(cfg, k_s),
-            "norm_f": layers.init_norm(cfg, cfg.d_model),
+        k_e, _, k_h, k_enc = self._init_keys(key)
+        d = cfg.d_model
+        draws = {
+            "embed": lambda: jax.random.normal(
+                k_e, (cfg.vocab_size, d), jnp.float32) * d ** -0.5,
+            "norm_f": lambda: layers.init_norm(cfg, d),
         }
         if not cfg.tie_embeddings:
-            params["lm_head"] = jax.random.normal(
-                k_h, (cfg.d_model, cfg.vocab_size), jnp.float32) * cfg.d_model ** -0.5
+            draws["lm_head"] = lambda: jax.random.normal(
+                k_h, (d, cfg.vocab_size), jnp.float32) * d ** -0.5
         if cfg.family == "encdec":
-            params["encoder"] = transformer.init_encoder_stack(cfg, k_enc)
-            params["enc_norm_f"] = layers.init_norm(cfg, cfg.d_model)
-        return params
+            draws["encoder"] = lambda: transformer.init_encoder_stack(
+                cfg, k_enc)
+            draws["enc_norm_f"] = lambda: layers.init_norm(cfg, d)
+        return draws
 
     def quantize(self, params, bits: int = None, pack: bool = False,
                  policy=None) -> dict:
@@ -58,6 +83,64 @@ class Model:
         precision ``policy`` ({"embed": 8, "w_down": 4, "default": 5})."""
         return quantizer.quantize_param_tree(params, bits, pack=pack,
                                              policy=policy)
+
+    def init_serving(self, key, bits: int = None, *, pack: bool = False,
+                     policy=None, precondition: int = 0) -> dict:
+        """Serving parameters without the float tree:
+        ``quantize(init(key), bits, pack, policy)`` — after
+        ``fake_quant_param_tree(init(key), precondition)`` when
+        ``precondition`` is set — built one layer at a time.
+
+        The float leaves are drawn from the same key split and by the same
+        eager ops as :meth:`init`, one scanned layer group at a time; each
+        is converted and only the result kept, written into its row of the
+        stacked leaf by a donated in-place update.  Scales reduce per layer
+        along the contraction axis, so the result equals the whole-tree
+        path bit for bit, while peak device memory is the serving tree plus
+        one layer's float leaves (or one f32 table, the embedding) —
+        not the float tree, which at qwen3-8b's widths is 32.8 GB.
+        ``bits=None`` with no policy keeps every leaf in float."""
+        cfg = self.cfg
+        policy = quantizer.parse_policy(policy)
+        quantized = bits is not None or bool(policy)
+
+        def convert(path, leaf):
+            name = quantizer._path_str(path)
+            if precondition:
+                leaf = quantizer.fake_quant_leaf(name, leaf, precondition)
+            if not quantized:
+                return leaf
+            return quantizer.quantize_leaf(name, leaf, bits, pack, policy)
+
+        quantizer.check_policy(policy, jax.eval_shape(self.init, key))
+        params = {}
+        for name, draw in self._outer_draws(key).items():
+            # one top-level float leaf alive at a time (the f32 embedding
+            # and lm_head are 2.5 GB each at qwen3-8b's widths)
+            top = (jax.tree_util.DictKey(name),)
+            params[name] = jax.tree_util.tree_map_with_path(
+                lambda path, leaf: convert(top + path, leaf), draw())
+        _, n_groups, tail_kinds = transformer._stack_groups(cfg)
+        keys = transformer.decoder_stack_keys(cfg, self._init_keys(key)[1])
+        group_path = (jax.tree_util.DictKey("stack"),
+                      jax.tree_util.DictKey("groups"))
+        groups = None
+        for i in range(n_groups):
+            row = jax.tree_util.tree_map_with_path(
+                lambda path, leaf: convert(group_path + path, leaf),
+                transformer.init_group(cfg, keys[i]))
+            if groups is None:
+                groups = jax.tree_util.tree_map(
+                    lambda a: jnp.zeros((n_groups,) + a.shape, a.dtype), row)
+            groups = _set_row(groups, i, row)
+        tail = [transformer.init_block(cfg, keys[n_groups + i], kind)
+                for i, kind in enumerate(tail_kinds)]
+        tail_path = (jax.tree_util.DictKey("stack"),
+                     jax.tree_util.DictKey("tail"))
+        tail = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: convert(tail_path + path, leaf), tail)
+        params["stack"] = {"groups": groups, "tail": tail}
+        return params
 
     # -------------------------------------------------------------- embedding
     def _embed_tokens(self, params, tokens, batch):

@@ -168,20 +168,28 @@ def _stack_groups(cfg):
     return [kinds[0]], cfg.n_layers, []
 
 
+def decoder_stack_keys(cfg, key):
+    """One key per scanned group, then one per unrolled tail block."""
+    _, n_groups, tail_kinds = _stack_groups(cfg)
+    return jax.random.split(key, n_groups + len(tail_kinds))
+
+
+def init_group(cfg, key):
+    """Parameters of ONE scanned group (unstacked) — a row of the stack's
+    ``groups`` leaves; ``Model.init_serving`` draws them a layer at a
+    time."""
+    group_kinds, _, _ = _stack_groups(cfg)
+    gks = jax.random.split(key, len(group_kinds))
+    return {f"b{i}_{kind}": init_block(cfg, gk, kind)
+            for i, (kind, gk) in enumerate(zip(group_kinds, gks))}
+
+
 def init_decoder_stack(cfg, key):
-    group_kinds, n_groups, tail_kinds = _stack_groups(cfg)
-    keys = jax.random.split(key, n_groups + len(tail_kinds))
-
-    def one_group(k):
-        gks = jax.random.split(k, len(group_kinds))
-        return {f"b{i}_{kind}": init_block(cfg, gk, kind)
-                for i, (kind, gk) in enumerate(zip(group_kinds, gks))}
-
+    _, n_groups, tail_kinds = _stack_groups(cfg)
+    keys = decoder_stack_keys(cfg, key)
     groups = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *[one_group(keys[i]) for i in range(n_groups)]
-    ) if n_groups > 1 else one_group(keys[0])
-    if n_groups == 1:
-        groups = jax.tree_util.tree_map(lambda x: x[None], groups)
+        lambda *xs: jnp.stack(xs),
+        *[init_group(cfg, keys[i]) for i in range(n_groups)])
     tail = [init_block(cfg, keys[n_groups + i], kind)
             for i, kind in enumerate(tail_kinds)]
     return {"groups": groups, "tail": tail}

@@ -20,10 +20,41 @@ import math
 
 from repro.configs import SHAPES, get_config
 
-# TPU v5e hardware constants (roofline denominators).
+# TPU v5e hardware constants (denominators of the ANALYTIC roofline, which
+# reports no measurement; measured metrics use ``device_peaks``).
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks, the denominators of measured utilization."""
+    bf16_flops: float            # FLOP/s
+    int8_ops: float              # OP/s
+    hbm_bytes_per_s: float
+
+
+# Keyed by ``jax.Device.device_kind`` (a v5e reports "TPU v5 lite").
+# Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(197e12, 393e12, 819e9),
+}
+
+
+def device_peaks(device) -> "DevicePeaks | None":
+    """Peaks of the chip ``device`` is, or None off-TPU (a CPU run measures
+    no device metric).  A TPU kind missing from the table is an error, not
+    a default."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r}; "
+            f"add it to DEVICE_PEAKS with its source") from None
 
 def _weight_bytes(quant: str) -> float:
     """Serving bytes/param for a quant mode: bf16 for "none", else the
@@ -146,10 +177,9 @@ def forward_flops(cfg, B, S, kind):
 def decode_macs_per_token(cfg, ctx_len: int) -> float:
     """Roofline MACs to emit ONE token at context length ``ctx_len`` —
     the per-token work term of the paper's MACs/W figure of merit, and the
-    numerator of serve_bench's MFU / tokens-per-joule columns:
+    numerator of the serve stats' MFU:
 
-        MFU              = macs*2 * tok_per_s / (PEAK_FLOPS * n_devices)
-        tokens_per_joule = tok_per_s / watts
+        MFU = macs*2 * tok_per_s / (device_peaks(dev).bf16_flops * n_devices)
 
     One decode step for one slot is ``forward_flops(cfg, B=1, S=ctx,
     kind="decode")``; a MAC is 2 FLOPs."""

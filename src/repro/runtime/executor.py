@@ -26,6 +26,7 @@ participates.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
 import numpy as np
@@ -40,6 +41,15 @@ from repro.models import kvcache as kvc
 from repro.runtime import sharding as shr
 from repro.runtime.elastic import make_mesh_from_plan, plan_remesh
 from repro.runtime.straggler import StragglerMonitor
+
+# XLA's TPU compiler computes a chain of bf16 elementwise ops in f32 inside a
+# fusion and rounds only where the fusion ends, so where it rounds depends
+# on how the program fused.  A sharded program fuses differently from the
+# one-device program (collectives split fusions): left to that, the same
+# model rounds in different places on a mesh and on one device, and greedy
+# tokens drift apart.  Every entry point compiles with bf16 results rounded
+# after each op, as written (DESIGN.md §5).
+STRICT_ROUNDING = {"xla_allow_excess_precision": False}
 
 
 def single_device_mesh():
@@ -268,16 +278,17 @@ class Executor:
         # now extends to shardings).  Greedy tokens replicate (host-read).
         tok_sh = jax.sharding.NamedSharding(
             self.mesh, jax.sharding.PartitionSpec())
-        self._prefill = jax.jit(self._prefill_fn)
+        self._prefill = self._jit(self._prefill_fn)
         if self.paged:
             # paged signatures carry the host-managed block-table tensors;
             # the donation + out_shardings contracts are identical, so the
             # decode step still compiles exactly once per mesh
-            self._decode = jax.jit(
+            self._decode = self._jit(
                 self._decode_fn_paged, donate_argnums=(5,),
                 out_shardings=(tok_sh, self.cache_shardings))
-            self._prefill_insert = jax.jit(
-                self._prefill_insert_fn_paged, donate_argnums=(3,),
+            self._prefill_insert = self._jit(
+                self._prefill_insert_fn_paged,
+                donate_argnums=(3,),
                 out_shardings=(tok_sh, self.cache_shardings))
             # prefix-cache twin: gathers the shared-prefix blocks out of
             # the (donated) pool as dense context KV, prefills only the
@@ -285,12 +296,14 @@ class Executor:
             # rows into the table's remaining blocks.  nctx is baked into
             # the ctx_ids shape, so each (bucket, nctx) pair is one
             # compiled executable; the decode step is untouched.
-            self._prefill_insert_prefix = jax.jit(
-                self._prefill_insert_fn_paged_prefix, donate_argnums=(3,),
+            self._prefill_insert_prefix = self._jit(
+                self._prefill_insert_fn_paged_prefix,
+                donate_argnums=(3,),
                 static_argnums=(7,),       # emit: chunked prefill skips the
                 out_shardings=(tok_sh, self.cache_shardings))  # lm-head
-            self._insert_burst = jax.jit(
-                self._insert_burst_fn_paged, donate_argnums=(0,),
+            self._insert_burst = self._jit(
+                self._insert_burst_fn_paged,
+                donate_argnums=(0,),
                 out_shardings=self.cache_shardings)
             if self.speculative is not None:
                 # the two (and only two) decode-side speculative shapes:
@@ -303,21 +316,21 @@ class Executor:
                 # pass's spec_tokens sharding, so the host can chain
                 # draft -> verify without a device round-trip (the verify
                 # builds its token window on device from the draft output)
-                self._spec_draft = jax.jit(
+                self._spec_draft = self._jit(
                     self._draft_fn_paged, donate_argnums=(5,),
                     out_shardings=(self._step_shardings["spec_tokens"],
                                    self.cache_shardings))
-                self._spec_verify = jax.jit(
+                self._spec_verify = self._jit(
                     self._verify_fn_paged, donate_argnums=(6,),
                     out_shardings=(tok_sh, self.cache_shardings))
         else:
-            self._decode = jax.jit(
+            self._decode = self._jit(
                 self._decode_fn, donate_argnums=(4,),
                 out_shardings=(tok_sh, self.cache_shardings))
-            self._prefill_insert = jax.jit(
+            self._prefill_insert = self._jit(
                 self._prefill_insert_fn, donate_argnums=(3,),
                 out_shardings=(tok_sh, self.cache_shardings))
-            self._insert_burst = jax.jit(
+            self._insert_burst = self._jit(
                 self._insert_burst_fn, donate_argnums=(0,),
                 out_shardings=self.cache_shardings)
 
@@ -344,11 +357,12 @@ class Executor:
                 shr.decode_carry_specs(cfg, self.mesh, carry_struct),
                 self.mesh)
             if self.paged:
-                self._decode_multi = jax.jit(
-                    self._decode_multi_fn_paged, donate_argnums=(7,),
+                self._decode_multi = self._jit(
+                    self._decode_multi_fn_paged,
+                    donate_argnums=(7,),
                     out_shardings=(tok_sh, carry_sh, self.cache_shardings))
             else:
-                self._decode_multi = jax.jit(
+                self._decode_multi = self._jit(
                     self._decode_multi_fn, donate_argnums=(6,),
                     out_shardings=(tok_sh, carry_sh, self.cache_shardings))
         else:
@@ -357,6 +371,28 @@ class Executor:
         # ---- elastic / straggler: no-op on a single-process mesh ----
         self.monitor = (StragglerMonitor(n_hosts=jax.process_count())
                         if jax.process_count() > 1 else None)
+
+    def _jit(self, fn, **kw):
+        """``jax.jit`` of a model entry point: traced under this executor's
+        mesh (:meth:`_in_mesh`) and compiled with strict bf16 rounding
+        (:data:`STRICT_ROUNDING`)."""
+        return jax.jit(self._in_mesh(fn), compiler_options=STRICT_ROUNDING,
+                       **kw)
+
+    def _in_mesh(self, fn):
+        """``fn`` traced under this executor's mesh (as an abstract-mesh
+        context) when it spans more than one device: the kernel dispatch
+        (``kernels.ops``) reads it to wrap Pallas calls in ``shard_map``,
+        since XLA cannot partition a Mosaic kernel itself."""
+        if self.mesh.size == 1:
+            return fn
+        abstract = self.mesh.abstract_mesh
+
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            with jax.sharding.use_abstract_mesh(abstract):
+                return fn(*args, **kwargs)
+        return traced
 
     # ------------------------------------------------------------ lifecycle
     @classmethod

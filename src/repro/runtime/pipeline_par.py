@@ -19,7 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(layer_fn, params_stacked, x_microbatches, mesh: Mesh,
@@ -76,11 +75,11 @@ def pipeline_apply(layer_fn, params_stacked, x_microbatches, mesh: Mesh,
 
     spec_p = jax.tree_util.tree_map(
         lambda l: P(stage_axis, *([None] * (l.ndim - 1))), params_stacked)
-    out = shard_map(
+    out = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(spec_p, P()),            # belt replicated; params staged
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x_microbatches)
     return out
 

@@ -25,10 +25,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.psi import QuantizedTensor
+from repro.launch.mesh import DP_AXES, data_axes  # noqa: F401 (re-export)
+from repro.launch.mesh import divisible as _divisible
+from repro.launch.mesh import pick_batch_axes as _pick_batch_axes
 from repro.models.kvcache import KVCache
 
 FSDP_AXIS = "data"
-DP_AXES = ("pod", "data")        # outer batch axes when present
 
 # Models narrower than this gain nothing from 16-way tensor parallelism —
 # per-shard GEMMs degenerate (d_ff/16 < MXU tile) and every layer pays two
@@ -44,32 +46,10 @@ def tp_enabled(cfg) -> bool:
 def _dp(mesh: Mesh, cfg=None) -> Tuple:
     """Data-parallel axes present in this mesh (flattened for batch dim).
     When TP is disabled for this arch, "model" joins the batch axes."""
-    axes = tuple(a for a in DP_AXES if a in mesh.axis_names)
+    axes = data_axes(mesh)
     if cfg is not None and not tp_enabled(cfg) and "model" in mesh.axis_names:
         axes = axes + ("model",)
     return axes
-
-
-def _divisible(n: int, mesh: Mesh, axes) -> bool:
-    if not axes:
-        return False
-    size = 1
-    for a in (axes if isinstance(axes, tuple) else (axes,)):
-        size *= mesh.shape[a]
-    return n % size == 0
-
-
-def _pick_batch_axes(B: int, mesh: Mesh, dp):
-    """Largest prefix of the data-parallel axes that divides the batch/slot
-    dim ``B`` (falls back to any single axis, then replication).  One shared
-    decision for batch inputs, decode-slot tensors, and the KV cache, so they
-    stay co-sharded."""
-    if _divisible(B, mesh, dp):
-        return dp
-    for k in range(len(dp) - 1, 0, -1):
-        if _divisible(B, mesh, dp[:k]):
-            return dp[:k]
-    return next((a for a in dp if B % mesh.shape[a] == 0), None)
 
 
 def batch_shard_count(cfg, mesh: Mesh, B: int) -> int:
@@ -159,17 +139,29 @@ _MOE_TP = {  # tensor parallelism inside each expert
 }
 
 
+# Serving splits these row-parallel GEMM weights over their OUTPUT dim
+# instead, like every other dense GEMM weight: each output column then sums
+# over the whole contraction on one device, in the single-device kernel's
+# order.  A split contraction ends in a psum that reorders the f32 sum, and
+# under bf16 activations that flips greedy tokens against one device.  The
+# price is an activation all-gather per GEMM — small at decode, where M is
+# the slot count.
+_SERVE_OUTPUT_SPLIT = re.compile(r"(^|/)(wo|w_down|w_out|x_proj|out_proj)$")
+
+
 def _path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
-def _logical_spec(path: str, cfg, mesh: Mesh) -> Optional[Tuple]:
+def _logical_spec(path: str, cfg, mesh: Mesh, mode: str) -> Optional[Tuple]:
     if cfg.n_experts and re.search(r"moe/", path):
         table = (_MOE_EP if _divisible(cfg.n_experts, mesh, "model")
                  else _MOE_TP)
         for pat, spec in table.items():
             if re.search(pat, path):
                 return spec
+    if mode == "serve" and _SERVE_OUTPUT_SPLIT.search(path):
+        return (None, "model")
     for pat, spec in _RULES:
         if re.search(pat, path):
             return spec
@@ -181,7 +173,7 @@ def _materialize(spec_tail, leaf_shape, mesh: Mesh, mode: str,
     """Map a logical trailing spec onto a concrete leaf shape; leading dims
     (layer stacks) replicate.  'fsdp' resolves to the data axes in train
     mode, None otherwise.  Axes that don't divide the dim are dropped."""
-    fsdp = tuple(a for a in DP_AXES if a in mesh.axis_names) if mode == "train" else None
+    fsdp = data_axes(mesh) if mode == "train" else None
     tail = []
     for dim, ax in zip(leaf_shape[-len(spec_tail):], spec_tail):
         if ax == "model" and not use_tp:
@@ -240,7 +232,7 @@ def param_specs(params, cfg, mesh: Mesh, mode: str = "serve"):
 
     def one(path, leaf):
         p = _path_str(path)
-        spec_tail = _logical_spec(p, cfg, mesh)
+        spec_tail = _logical_spec(p, cfg, mesh, mode)
         if _is_qt(leaf):
             if spec_tail is None:
                 return QuantizedTensor(P(), P(), leaf.fmt, leaf.packed)

@@ -131,29 +131,44 @@ class TestHorizonIdentity:
         """M=4: pick an EOS id that lands at each in-round offset
         {0, 1, 2, 3} of a single request's stream; horizon-4 retires the
         slot inside the scan and still matches horizon 1 exactly (the EOS
-        token itself is emitted, then the row masks off)."""
+        token itself is emitted, then the row masks off).
+
+        An offset is reachable only through a token whose FIRST occurrence
+        lands there (an earlier copy would retire the stream first), and
+        which offsets a random model's greedy stream reaches depends on the
+        RNG; so the trace seed is searched until a stream reaches >= 3."""
         cfg, params = qwen_setup
         ref = Server(cfg, params, max_batch=1, max_seq=64)
-        d_ref, _ = ref.serve(_requests(cfg, [(0.0, 12)], seed=5),
-                             continuous=True)
-        stream = list(d_ref[0].tokens)
-        # decode emission i is stream[1 + i] (stream[0] comes from
-        # prefill); its in-round offset at M=4 is i % 4.
+
+        def first_hits(stream):
+            # decode emission i is stream[1 + i] (stream[0] comes from
+            # prefill); its in-round offset at M=4 is i % 4.
+            out = {}
+            for off in range(4):
+                idx = next((1 + i for i in range(len(stream) - 1)
+                            if i % 4 == off
+                            and stream[1 + i] not in stream[:1 + i]), None)
+                if idx is not None:
+                    out[off] = idx
+            return out
+
+        for seed in range(5, 37):
+            d_ref, _ = ref.serve(_requests(cfg, [(0.0, 12)], seed=seed),
+                                 continuous=True)
+            stream = list(d_ref[0].tokens)
+            targets = first_hits(stream)
+            if len(targets) >= 3:
+                break
         hit = 0
-        for off in range(4):
-            idx = next((1 + i for i in range(len(stream) - 1)
-                        if i % 4 == off
-                        and stream[1 + i] not in stream[:1 + i]), None)
-            if idx is None:
-                continue                      # eos would truncate earlier
+        for off, idx in targets.items():
             hit += 1
             eos = int(stream[idx])
             h1 = Server(cfg, params, max_batch=1, max_seq=64, eos_id=eos)
             h4 = Server(cfg, params, max_batch=1, max_seq=64, eos_id=eos,
                         decode_horizon=4)
-            t1 = _toks(h1.serve(_requests(cfg, [(0.0, 12)], seed=5),
+            t1 = _toks(h1.serve(_requests(cfg, [(0.0, 12)], seed=seed),
                                 continuous=True)[0])
-            t4 = _toks(h4.serve(_requests(cfg, [(0.0, 12)], seed=5),
+            t4 = _toks(h4.serve(_requests(cfg, [(0.0, 12)], seed=seed),
                                 continuous=True)[0])
             assert t1 == t4, off
             assert t4[0][-1] == eos and len(t4[0]) == idx + 1, off

@@ -231,6 +231,45 @@ class TestPagedEngine:
         assert stat_p["decode_compiles"] == 1
         assert stat_p["blocks_free_end"] == stat_p["n_blocks"]
 
+    def test_final_state_holds_live_pool_and_tables(self, qwen_setup):
+        """After a serve the engine keeps the decode cache as the last step
+        left it and each request's block-table row as it stood when the
+        request retired; the paged-decode kernel reads that live state
+        as its oracle does."""
+        from repro.kernels import paged_attention as pa
+        cfg, params = qwen_setup
+        server = Server(cfg, params, max_batch=2, max_seq=48)
+        assert server.final_state is None
+        reqs = _requests([(0.0, 5), (0.0, 9), (0.001, 3), (0.002, 7)],
+                         prompt_len=11)
+        done, stats = server.serve(reqs, continuous=True)
+        rows = server.final_state["block_rows"]
+        assert sorted(rows) == [r.rid for r in sorted(done,
+                                                      key=lambda r: r.rid)]
+        for r in done:
+            row = rows[r.rid]
+            ids = row[row >= 0]
+            written = len(r.prompt) + len(r.tokens) - 1
+            assert len(ids) >= kvc.blocks_for(written, server.block_size)
+            assert len(set(ids.tolist())) == len(ids)
+            assert ids.max() < stats["n_blocks"]
+        last = sorted(done, key=lambda r: r.finish_s)[-2:]
+        bt = np.stack([rows[r.rid] for r in last])
+        pos = np.asarray([len(r.prompt) + len(r.tokens) - 2 for r in last],
+                         np.int32)
+        pool = server.final_state["cache"].kv[0]["b0"]
+        k_pool, v_pool = pool["k"][0], pool["v"][0]
+        q = jax.random.normal(jax.random.PRNGKey(3),
+                              (2, cfg.n_heads, cfg.resolved_head_dim),
+                              k_pool.dtype)
+        args = (q, k_pool, v_pool, bt, pos)
+        assert np.abs(np.asarray(k_pool)[bt[bt >= 0]]).max() > 0
+        np.testing.assert_allclose(
+            np.asarray(pa.paged_attention_pallas(*args, interpret=True)),
+            np.asarray(pa.paged_attention_ref(*args)), rtol=1e-5, atol=1e-5)
+        server.serve(_requests([(0.0, 2)]), continuous=True)
+        assert sorted(server.final_state["block_rows"]) == [0]
+
     def test_engine_churn_returns_all_blocks(self, qwen_setup):
         """Engine-level fragmentation regression: many heterogeneous
         admit/retire cycles through a small paged pool end with every

@@ -210,6 +210,20 @@ class TestRouting:
         with pytest.raises(ValueError, match="REPRO_PAGED_ATTN"):
             ops.paged_attn_route()
 
+    def test_interpret_on_tpu_is_an_error(self, monkeypatch):
+        """On a TPU backend nothing may swap the interpreter in for the
+        chip: both interpret switches raise instead of running."""
+        monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+        monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+        monkeypatch.setenv("REPRO_PAGED_ATTN", "interpret")
+        with pytest.raises(RuntimeError, match="REPRO_PAGED_ATTN"):
+            ops.paged_attn_route()
+        monkeypatch.delenv("REPRO_PAGED_ATTN")
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+        for route in (ops.paged_attn_route, ops.matmul_route):
+            with pytest.raises(RuntimeError, match="REPRO_FORCE_INTERPRET"):
+                route()
+
     def test_routed_interpret_matches_oracle(self, monkeypatch):
         q, kp, vp, bt, pos, ks, vs = _case(7, 2, 3, "bf16")
         monkeypatch.delenv("REPRO_PAGED_ATTN", raising=False)

@@ -233,3 +233,43 @@ class TestSubByteServing:
         stack_wq = p["stack"]["groups"]["b0_attn"]["attn"]["wq"]
         assert stack_wq.fmt.bits == 5 and stack_wq.packed
         assert cfg.quant_mode == "psi5"
+
+
+class TestLayerwiseServingParams:
+    """``Model.init_serving`` builds the serving tree a layer at a time,
+    never holding the float tree, and must equal the whole-tree path."""
+
+    @pytest.mark.parametrize("arch", ["qwen3-8b", "qwen3-moe-30b-a3b"])
+    def test_equals_quantize_of_init(self, arch):
+        from repro.configs import get_config, reduced_config
+        from repro.models import build_model
+        model = build_model(reduced_config(get_config(arch)))
+        key = jax.random.PRNGKey(0)
+        want = model.quantize(model.init(key), 8, pack=True)
+        got = model.init_serving(key, 8, pack=True)
+        is_qt = lambda x: isinstance(x, psi.QuantizedTensor)  # noqa: E731
+        assert (jax.tree_util.tree_structure(got, is_leaf=is_qt)
+                == jax.tree_util.tree_structure(want, is_leaf=is_qt))
+        for (path, a), b in zip(
+                jax.tree_util.tree_leaves_with_path(got),
+                jax.tree_util.tree_leaves(want)):
+            assert a.dtype == b.dtype and a.shape == b.shape, path
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=jax.tree_util.keystr(path))
+
+    def test_policy_and_precondition_match(self):
+        """Mixed precision (packed sub-byte leaves) over a QAT-
+        preconditioned checkpoint: still the whole-tree result exactly."""
+        from repro.configs import get_config, reduced_config
+        from repro.core.quantizer import fake_quant_param_tree
+        from repro.models import build_model
+        model = build_model(reduced_config(get_config("qwen3-8b")))
+        key = jax.random.PRNGKey(1)
+        pol = "embed=8,w_down=4,default=5"
+        want = model.quantize(fake_quant_param_tree(model.init(key), 3),
+                              None, pack=True, policy=pol)
+        got = model.init_serving(key, None, pack=True, policy=pol,
+                                 precondition=3)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

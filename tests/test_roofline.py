@@ -12,13 +12,6 @@ from repro.perf.roofline_model import (analytic_cell, forward_flops,
                                        weight_bytes_total)
 
 
-def _cost_analysis(compiled):
-    """jax < 0.5 returns a per-device list from cost_analysis(); >= 0.5 a
-    single dict."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, list) else ca
-
-
 def test_terms_positive_all_cells():
     for a in ASSIGNED_ARCHS:
         cfg = get_config(a)
@@ -93,7 +86,7 @@ def test_cross_validate_against_unrolled_hlo():
         return model.forward(p, b)[0]
 
     compiled = jax.jit(fwd).lower(params, batch).compile()
-    hlo_flops = _cost_analysis(compiled)["flops"]
+    hlo_flops = compiled.cost_analysis()["flops"]
     ours = forward_flops(cfg, B, S, "prefill")
     assert 0.65 < ours / hlo_flops < 1.35, (ours, hlo_flops)
 
@@ -112,5 +105,5 @@ def test_scan_undercount_demonstrated():
         params = model.init(jax.random.PRNGKey(0))
         compiled = jax.jit(
             lambda p, b: model.forward(p, b)[0]).lower(params, batch).compile()
-        flops[scan] = _cost_analysis(compiled)["flops"]
+        flops[scan] = compiled.cost_analysis()["flops"]
     assert flops[True] < 0.55 * flops[False]
