@@ -70,7 +70,7 @@ import jax
 
 from repro.core.quantizer import parse_quant_mode
 from repro.launch.compile_cache import use_compile_cache
-from repro.launch.serve import (add_serve_args, build_server, format_mfu,
+from repro.launch.serve import (add_serve_args, build_server,
                                 trace_from_args)
 from repro.launch.slo import bursty_heavy_tail_trace, parse_slo_spec
 
@@ -510,8 +510,7 @@ def run_bench(args, out_path=None):
     done_hM, stat_hM = median_multi_serve(hM_server)
     _assert_identical(done_h1, done_hM, f"decode horizon 1/{h_hi}")
     for st in (stat_h1, stat_hM):            # serving-metrics contract
-        for key in ("host_syncs", "host_syncs_per_token", "mfu",
-                    "macs_per_token"):
+        for key in ("host_syncs", "host_syncs_per_token"):
             assert key in st, f"stats missing {key!r}"
     sync_ratio = (stat_h1["host_syncs_per_token"]
                   / stat_hM["host_syncs_per_token"]
@@ -544,8 +543,7 @@ def run_bench(args, out_path=None):
           f"{stat_h1['host_syncs_per_token']:.3f} syncs/tok "
           f"({sync_ratio:.1f}x fewer) | {stat_hM['tok_per_s']:.1f} vs "
           f"{stat_h1['tok_per_s']:.1f} tok/s ({multi_ratio:.2f}x) | "
-          f"mfu {format_mfu(stat_hM['mfu'])} | mesh "
-          f"{mesh_identity}")
+          f"mesh {mesh_identity}")
     payload["multistep"] = {
         "token_identical": True,
         "horizon": h_hi,
@@ -557,7 +555,6 @@ def run_bench(args, out_path=None):
         "speedup": round(multi_ratio, 3),
         "decode_rounds": stat_hM.get("decode_rounds", 0),
         "decode_compiles": stat_hM["decode_compiles"],
-        "mfu": stat_hM["mfu"],
         "mesh_identity": mesh_identity,
         "h1": stat_h1,
         "hM": stat_hM,
@@ -604,8 +601,7 @@ def run():
         ms = d["multistep"]
         derived += (f";horizon{ms['horizon']}_sync_ratio="
                     f"{ms['sync_ratio']:.2f}x"
-                    f";horizon_speedup={ms['speedup']:.2f}x"
-                    f";mfu={format_mfu(ms['mfu'])}")
+                    f";horizon_speedup={ms['speedup']:.2f}x")
     return [("serve_bench", us, derived)]
 
 

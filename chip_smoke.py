@@ -49,7 +49,7 @@ from repro.core.quantizer import quantized_bytes  # noqa: E402
 from repro.kernels import ops, paged_attention, ref  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.serve import (add_serve_args, build_server,  # noqa: E402
-                                format_mfu, trace_from_args)
+                                trace_from_args)
 from repro.perf.roofline_model import device_peaks  # noqa: E402
 
 SERVE_ARGS = ["--arch", "qwen3-8b", "--quant", "psi8", "--max-batch", "8",
@@ -105,7 +105,6 @@ def _serve(args):
             "decode_compiles", "slot_shards", "paged_attn_route",
             "peak_concurrency")
     smoke = {k: stats.get(k) for k in keys}
-    smoke["mfu"] = format_mfu(stats["mfu"])
     print(f"  serve stats (smoke run, not a benchmark result): "
           f"{json.dumps(smoke)}")
     _check(stats["decode_compiles"] == 1,

@@ -55,12 +55,14 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import gc
 import math
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs import get_config, reduced_config
 from repro.core.quantizer import (parse_policy, parse_quant_mode,
@@ -72,7 +74,6 @@ from repro.launch.scheduler import (BlockAllocator, Request, Scheduler,
                                     poisson_trace, replay_round, summarize)
 from repro.launch.slo import parse_slo_spec, slo_report
 from repro.models import build_model, kvcache as kvc
-from repro.perf.roofline_model import decode_macs_per_token, device_peaks
 from repro.runtime.executor import Executor
 
 # Prompt lengths are rounded up to a multiple of this before prefill so the
@@ -294,6 +295,18 @@ class Server:
             return n
         return sb
 
+    def _read(self, x, span: str, **args) -> np.ndarray:
+        """Blocking device-to-host read of ``x`` under the profiler span
+        ``span`` (``prefill.wait`` or ``decode.wait``).  Every wait of the
+        serve loop comes through here: it counts one host sync and leaves
+        its time out of ``host_busy_s``."""
+        t = time.perf_counter()
+        with TraceAnnotation(span, **args):
+            out = np.asarray(x)
+        self._syncs += 1
+        self._wait_s += time.perf_counter() - t
+        return out
+
     def _prefill_admits(self, cache, admits: Sequence[Tuple[int, Request]],
                         sched: Optional[Scheduler] = None, bt=None,
                         chunking: Optional[Dict[int, int]] = None):
@@ -365,31 +378,40 @@ class Server:
             return firsts, cache
         for _, req in admits:            # leaf call: the prefill really runs
             req.prefilled_tokens += len(req.prompt)
-        B = 1 if len(admits) == 1 else self.max_batch
-        toks = np.zeros((B, sb), np.int32)
-        tl = np.ones((B,), np.int32)
-        for i, (_, req) in enumerate(admits):
-            toks[i, :len(req.prompt)] = req.prompt
-            tl[i] = len(req.prompt)
-        if len(admits) == 1:                     # fused prefill + insert
-            slot = admits[0][0]
-            row = bt[slot] if self.paged else None
-            first, cache = self.executor.prefill_insert(toks, tl, cache,
-                                                        slot, block_row=row)
+        # a burst's spans name every request it prefills
+        rid = (admits[0][1].rid if len(admits) == 1
+               else ",".join(str(r.rid) for _, r in admits))
+        with TraceAnnotation("prefill.dispatch", rid=rid, offset=0,
+                             tokens=sum(lens)):
+            B = 1 if len(admits) == 1 else self.max_batch
+            toks = np.zeros((B, sb), np.int32)
+            tl = np.ones((B,), np.int32)
+            for i, (_, req) in enumerate(admits):
+                toks[i, :len(req.prompt)] = req.prompt
+                tl[i] = len(req.prompt)
+            if len(admits) == 1:                 # fused prefill + insert
+                slot = admits[0][0]
+                row = bt[slot] if self.paged else None
+                first, cache = self.executor.prefill_insert(
+                    toks, tl, cache, slot, block_row=row)
+            else:
+                first, seq_cache = self.executor.prefill(toks, tl)
+        first = self._read(first, "prefill.wait", rid=rid)
+        if len(admits) == 1:
             return [int(first[0])], cache
-        first, seq_cache = self.executor.prefill(toks, tl)
-        first = np.asarray(first)
-        slots = np.zeros((self.max_batch,), np.int32)
-        valid = np.zeros((self.max_batch,), bool)
-        rows = (np.full((self.max_batch, self.executor.n_bt), -1, np.int32)
-                if self.paged else None)
-        for i, (slot, _) in enumerate(admits):
-            slots[i] = slot
-            valid[i] = True
-            if self.paged:
-                rows[i] = bt[slot]
-        cache = self.executor.insert_burst(cache, seq_cache, slots, valid,
-                                           block_rows=rows)
+        with TraceAnnotation("prefill.dispatch", rid=rid, offset=0,
+                             tokens=0):
+            slots = np.zeros((self.max_batch,), np.int32)
+            valid = np.zeros((self.max_batch,), bool)
+            rows = (np.full((self.max_batch, self.executor.n_bt), -1,
+                            np.int32) if self.paged else None)
+            for i, (slot, _) in enumerate(admits):
+                slots[i] = slot
+                valid[i] = True
+                if self.paged:
+                    rows[i] = bt[slot]
+            cache = self.executor.insert_burst(cache, seq_cache, slots,
+                                               valid, block_rows=rows)
         return [int(first[i]) for i in range(len(admits))], cache
 
     def _begin_fill(self, cache, slot, req, sched, bt,
@@ -434,18 +456,22 @@ class Server:
         final = not (self.prefill_chunk and rem > self.prefill_chunk)
         n = self._bucket_len(rem) if final else self.prefill_chunk
         take = rem if final else n
-        pref = self._block_pref(slot)
-        for j in range(cur // bs, kvc.blocks_for(cur + n, bs)):
-            if bt[slot, j] < 0:
-                bt[slot, j] = sched.blocks.alloc(req.rid, shard=pref)
-        toks = np.zeros((1, n), np.int32)
-        toks[0, :take] = seq[cur:cur + take]
-        tl = np.asarray([take], np.int32)
-        req.prefilled_tokens += int(take)
-        first, cache = self.executor.prefill_insert(
-            toks, tl, cache, slot, block_row=bt[slot],
-            ctx_ids=bt[slot, :cur // bs], emit=final)
-        return (int(first[0]) if final else None), cache
+        with TraceAnnotation("prefill.dispatch", rid=req.rid, offset=cur,
+                             tokens=int(take)):
+            pref = self._block_pref(slot)
+            for j in range(cur // bs, kvc.blocks_for(cur + n, bs)):
+                if bt[slot, j] < 0:
+                    bt[slot, j] = sched.blocks.alloc(req.rid, shard=pref)
+            toks = np.zeros((1, n), np.int32)
+            toks[0, :take] = seq[cur:cur + take]
+            tl = np.asarray([take], np.int32)
+            req.prefilled_tokens += int(take)
+            first, cache = self.executor.prefill_insert(
+                toks, tl, cache, slot, block_row=bt[slot],
+                ctx_ids=bt[slot, :cur // bs], emit=final)
+        if not final:
+            return None, cache
+        return int(self._read(first, "prefill.wait", rid=req.rid)[0]), cache
 
     def _advance_chunk(self, cache, slot, sched, bt,
                        chunking: Dict[int, int]):
@@ -628,47 +654,54 @@ class Server:
         ex = self.executor
         K = self.spec_k
         t_draft = time.perf_counter()
-        drafts_dev, cache = ex.draft(tok, pos, act, cache, bt)
-        # the verify window is assembled on device from the draft output,
-        # so both dispatches enqueue back-to-back with no host round-trip
-        verdicts, cache = ex.verify(tok, drafts_dev, pos, act, cache, bt)
-        drafts = np.asarray(drafts_dev)
+        with TraceAnnotation("decode.dispatch", n_active=int(act.sum())):
+            drafts_dev, cache = ex.draft(tok, pos, act, cache, bt)
+            # the verify window is assembled on device from the draft
+            # output, so both dispatches enqueue back-to-back with no host
+            # round-trip
+            verdicts, cache = ex.verify(tok, drafts_dev, pos, act, cache,
+                                        bt)
+        drafts = self._read(drafts_dev, "decode.wait", what="draft")
         # drafts materialize as soon as the draft executable finishes (the
         # verify is merely queued behind it), so this measures the round's
         # draft side; the verify sync below is the target-model cost any
         # decode engine pays
         draft_dt = time.perf_counter() - t_draft
-        verdicts = np.asarray(verdicts)
+        verdicts = self._read(verdicts, "decode.wait", what="verdict")
         now = now_fn()
         share = draft_dt / max(int(act.sum()), 1)
-        for slot in list(sched.running):
-            if not act[slot]:
-                continue        # chunking slot: masked out of the round
-            req = sched.running[slot]
-            req.draft_s += share
-            d, v = drafts[slot], verdicts[slot]
-            a = 0
-            while a < K and d[a] == v[a]:
-                a += 1
-            emit = [int(x) for x in d[:a]]
-            if a < K:
-                emit.append(int(v[a]))
-            req.spec_rounds += 1
-            req.spec_accepted += a
-            finished = False
-            n_emit = 0
-            for t in emit:
-                req.emit(t, now)
-                n_emit += 1
-                if t == self.eos_id or len(req.tokens) >= req.max_new:
-                    finished = True
-                    break
-            pos[slot, 0] += n_emit
-            if finished:
-                act[slot] = False
-                retire(slot, now)
-            else:
-                tok[slot, 0] = emit[n_emit - 1]
+        n_retired = 0
+        with TraceAnnotation("decode.emit") as ann:
+            for slot in list(sched.running):
+                if not act[slot]:
+                    continue        # chunking slot: masked out of the round
+                req = sched.running[slot]
+                req.draft_s += share
+                d, v = drafts[slot], verdicts[slot]
+                a = 0
+                while a < K and d[a] == v[a]:
+                    a += 1
+                emit = [int(x) for x in d[:a]]
+                if a < K:
+                    emit.append(int(v[a]))
+                req.spec_rounds += 1
+                req.spec_accepted += a
+                finished = False
+                n_emit = 0
+                for t in emit:
+                    req.emit(t, now)
+                    n_emit += 1
+                    if t == self.eos_id or len(req.tokens) >= req.max_new:
+                        finished = True
+                        break
+                pos[slot, 0] += n_emit
+                if finished:
+                    act[slot] = False
+                    retire(slot, now)
+                    n_retired += 1
+                else:
+                    tok[slot, 0] = emit[n_emit - 1]
+            ann.set_metadata(n_retired=n_retired)
         return cache
 
     # ------------------------------------------------------------- the loop
@@ -686,7 +719,40 @@ class Server:
         block-table row as it stood when the request retired (paged layout;
         rows are freed at retirement, so a row's blocks may since hold
         later requests' writes).
+
+        While a ``jax.profiler`` session is on, the loop writes host spans
+        into it on the device's clock: ``serve.window`` around the served
+        window, ``serve.iter`` per loop iteration, and within it
+        ``sched.admit``, ``prefill.dispatch``/``prefill.wait``,
+        ``kv.alloc``, ``decode.dispatch``/``decode.wait``,
+        ``decode.emit``, ``idle.sleep`` and ``gc`` (every Python
+        collection).  With no session they cost about a microsecond each.
         """
+        gc_open = []                # (span, start) of a running collection
+        gc_pauses = [0, 0.0]
+
+        def on_gc(phase, info):
+            if phase == "start":
+                ann = TraceAnnotation("gc", generation=info["generation"])
+                ann.__enter__()
+                gc_open.append((ann, time.perf_counter()))
+            elif gc_open:
+                ann, t = gc_open.pop()
+                ann.__exit__(None, None, None)
+                gc_pauses[0] += 1
+                gc_pauses[1] += time.perf_counter() - t
+
+        gc.callbacks.append(on_gc)
+        try:
+            finished, stats = self._serve(requests, continuous, warmup)
+        finally:
+            gc.callbacks.remove(on_gc)
+        stats["gc_pauses"], stats["gc_pause_s"] = gc_pauses
+        return finished, stats
+
+    def _serve(self, requests: Sequence[Request], continuous: bool,
+               warmup: bool):
+        """The body of :meth:`serve`."""
         clock = time.perf_counter
         ex = self.executor
 
@@ -774,8 +840,9 @@ class Server:
         steps = 0
         n_chunks = 0
         rounds = 0
-        host_syncs = 0                     # host-blocking d2h syncs
         loop_iters = 0
+        sleep_s = 0.0                      # idle.sleep, waiting to arrive
+        self._syncs, self._wait_s = 0, 0.0     # see _read
         peak_running = 0
         M = self.decode_horizon
         multi = M > 1
@@ -789,6 +856,8 @@ class Server:
         pipeline = multi and self.slo is None and not self.prefill_chunk
         pending = None        # in-flight round's (M, B) device tokens
         carry = None          # device carry chained round-to-round
+        window = TraceAnnotation("serve.window", n_requests=len(requests))
+        window.__enter__()
         t0 = clock()
 
         def process_toks(toks_dev) -> None:
@@ -797,24 +866,26 @@ class Server:
             EOS/budget-exhausted slots, and leave tok/pos/act/rem exactly
             equal to the device carry row-for-row (the identity argument in
             DESIGN.md — same recurrence, same state)."""
-            nonlocal host_syncs
-            toks = np.asarray(toks_dev)                  # (M, B) host sync
-            host_syncs += 1
+            toks = self._read(toks_dev, "decode.wait")   # (M, B)
             now = clock() - t0
             emitted, act_out, rem_out = replay_round(toks, act, rem,
                                                      self.eos_id)
-            for slot in list(sched.running):
-                if not emitted[slot]:
-                    continue       # not entry-active (free / chunking slot)
-                req = sched.running[slot]
-                for t in emitted[slot]:
-                    req.emit(t, now)
-                pos[slot, 0] += len(emitted[slot])
-                tok[slot, 0] = emitted[slot][-1]
-                rem[slot] = rem_out[slot]
-                if not act_out[slot]:
-                    act[slot] = False
-                    retire(slot, now)
+            n_retired = 0
+            with TraceAnnotation("decode.emit") as ann:
+                for slot in list(sched.running):
+                    if not emitted[slot]:
+                        continue   # not entry-active (free / chunking slot)
+                    req = sched.running[slot]
+                    for t in emitted[slot]:
+                        req.emit(t, now)
+                    pos[slot, 0] += len(emitted[slot])
+                    tok[slot, 0] = emitted[slot][-1]
+                    rem[slot] = rem_out[slot]
+                    if not act_out[slot]:
+                        act[slot] = False
+                        retire(slot, now)
+                        n_retired += 1
+                ann.set_metadata(n_retired=n_retired)
 
         def drain() -> None:
             """Process the in-flight round, if any.  MUST run before any
@@ -891,129 +962,145 @@ class Server:
 
         while not sched.done:
             loop_iters += 1
-            now = clock() - t0
-            sched.poll(now)
-            if continuous or not sched.running:
-                admits = sched.admit(now)
+            with StepTraceAnnotation("serve.iter", step_num=loop_iters):
+                now = clock() - t0
+                with TraceAnnotation("sched.admit") as ann:
+                    sched.poll(now)
+                    admits = (sched.admit(now)
+                              if continuous or not sched.running else [])
+                    ann.set_metadata(n_admitted=len(admits))
                 if admits:
                     drain()      # mirrors must be current before emit_first
                     firsts, cache = self._prefill_admits(cache, admits,
                                                          sched, bt, chunking)
-                    if any(f is not None for f in firsts):
-                        host_syncs += 1
                     now = clock() - t0
                     peak_running = max(peak_running, len(sched.running))
                     for (slot, req), first in zip(admits, firsts):
                         if first is None:
                             continue     # chunking: nothing emitted yet
                         emit_first(slot, req, first, now)
-            if chunking:
-                # one piece per loop iteration (lowest slot first, for
-                # determinism): a long prefill interleaves with decode
-                # steps instead of stalling every running request
-                slot = min(chunking)
-                first, cache = self._advance_chunk(cache, slot, sched, bt,
-                                                   chunking)
-                n_chunks += 1
-                if first is not None:
-                    host_syncs += 1
-                    emit_first(slot, sched.running[slot], first,
-                               clock() - t0)
-            if not sched.running:
-                if sched.waiting:
-                    continue   # slots free (instant retirements): re-admit
-                nxt = sched.next_arrival_s()
-                if nxt is None:
-                    break                      # everything drained
-                wait = nxt - (clock() - t0)
-                if wait > 0:
-                    # sleep the actual remaining gap (capped so a clock
-                    # hiccup can't oversleep an arrival by much) — the old
-                    # 5 ms slices busy-spun O(gap / 5ms) iterations per
-                    # arrival gap on sparse traces
-                    time.sleep(min(wait, 0.25))
-                continue
-            if not act.any():
-                continue       # every running slot is still mid-chunking
-            if self.paged:
-                # alloc-on-demand: every block this step's writes can touch
-                # must exist before the step runs.  FIFO mode reserved the
-                # worst case at admission so the alloc cannot fail; the SLO
-                # policy's optimistic reservation secures the shortfall
-                # here (eviction, then preemption).  A plain step writes
-                # one position; a speculative round writes k consecutive; a
-                # multi-step round writes up to M — and with a round in
-                # flight the device carry can already sit M ahead of the
-                # host mirror, so the pipelined span doubles.  Positions
-                # past the request's final feed (prompt + max_new - 2)
-                # are never written, so the span is capped there and the
-                # FIFO worst-case reservation still covers it.
-                span = max(self.spec_k, M)
-                if pipeline and pending is not None:
-                    span += M
-                for slot, req in list(sched.running.items()):
-                    if not act[slot]:
-                        continue        # chunking, or preempted just now
-                    p0 = int(pos[slot, 0])
-                    hi = min(p0 + span - 1,
-                             len(req.prompt) + req.max_new - 2)
-                    for li in range(p0 // self.block_size,
-                                    hi // self.block_size + 1):
-                        if bt[slot, li] < 0:
-                            if (self.slo is not None
-                                    and not secure_one(req)):
-                                break   # req itself yielded its slot
-                            bt[slot, li] = sched.blocks.alloc(
-                                req.rid, shard=self._block_pref(slot))
+                if chunking:
+                    # one piece per loop iteration (lowest slot first, for
+                    # determinism): a long prefill interleaves with decode
+                    # steps instead of stalling every running request
+                    slot = min(chunking)
+                    first, cache = self._advance_chunk(cache, slot, sched,
+                                                       bt, chunking)
+                    n_chunks += 1
+                    if first is not None:
+                        emit_first(slot, sched.running[slot], first,
+                                   clock() - t0)
+                if not sched.running:
+                    if sched.waiting:
+                        continue   # slots free (instant retirements)
+                    nxt = sched.next_arrival_s()
+                    if nxt is None:
+                        break                      # everything drained
+                    wait = nxt - (clock() - t0)
+                    if wait > 0:
+                        # sleep the actual remaining gap (capped so a clock
+                        # hiccup can't oversleep an arrival by much) — the
+                        # old 5 ms slices busy-spun O(gap / 5ms) iterations
+                        # per arrival gap on sparse traces
+                        t_sleep = clock()
+                        with TraceAnnotation("idle.sleep"):
+                            time.sleep(min(wait, 0.25))
+                        sleep_s += clock() - t_sleep
+                    continue
                 if not act.any():
-                    continue   # pressure path preempted every decoder
-            if self.spec:
-                cache = self._spec_round(sched, cache, tok, pos, act, bt,
-                                         lambda: clock() - t0, retire)
-                host_syncs += 2          # draft + verdict materializations
+                    continue       # every running slot is still mid-chunking
+                if self.paged:
+                    # alloc-on-demand: every block this step's writes can
+                    # touch must exist before the step runs.  FIFO mode
+                    # reserved the worst case at admission so the alloc
+                    # cannot fail; the SLO policy's optimistic reservation
+                    # secures the shortfall here (eviction, then
+                    # preemption).  A plain step writes one position; a
+                    # speculative round writes k consecutive; a multi-step
+                    # round writes up to M — and with a round in flight
+                    # the device carry can already sit M ahead of the host
+                    # mirror, so the pipelined span doubles.  Positions
+                    # past the request's final feed (prompt + max_new - 2)
+                    # are never written, so the span is capped there and
+                    # the FIFO worst-case reservation still covers it.
+                    span = max(self.spec_k, M)
+                    if pipeline and pending is not None:
+                        span += M
+                    with TraceAnnotation("kv.alloc",
+                                         n_active=int(act.sum())):
+                        for slot, req in list(sched.running.items()):
+                            if not act[slot]:
+                                continue    # chunking, or preempted now
+                            p0 = int(pos[slot, 0])
+                            hi = min(p0 + span - 1,
+                                     len(req.prompt) + req.max_new - 2)
+                            for li in range(p0 // self.block_size,
+                                            hi // self.block_size + 1):
+                                if bt[slot, li] < 0:
+                                    if (self.slo is not None
+                                            and not secure_one(req)):
+                                        break   # req itself yielded
+                                    bt[slot, li] = sched.blocks.alloc(
+                                        req.rid,
+                                        shard=self._block_pref(slot))
+                    if not act.any():
+                        continue   # pressure path preempted every decoder
+                if self.spec:
+                    cache = self._spec_round(sched, cache, tok, pos, act,
+                                             bt, lambda: clock() - t0,
+                                             retire)
+                    steps += 1
+                    continue
+                if multi:
+                    # one horizon-M round: chained from the device carry
+                    # when the host hasn't touched its mirrors since the
+                    # last round (zero carry upload in steady state),
+                    # rebuilt from the mirrors otherwise
+                    src = carry if carry is not None else {
+                        "token": tok, "pos": pos, "active": act,
+                        "remaining": rem}
+                    with TraceAnnotation("decode.dispatch",
+                                         n_active=int(act.sum())):
+                        toks_dev, carry, cache = ex.decode_multi(
+                            src["token"], src["pos"], src["active"],
+                            src["remaining"], cache, block_table=bt,
+                            eos_id=self.eos_id)
+                    steps += M
+                    rounds += 1
+                    prev, pending = pending, toks_dev
+                    if prev is not None:
+                        # double buffer: the device is already running
+                        # round N+1 while the host replays round N here
+                        process_toks(prev)
+                    if not pipeline:
+                        drain()
+                    continue
+                with TraceAnnotation("decode.dispatch",
+                                     n_active=int(act.sum())):
+                    new_tok, cache = ex.decode(tok, pos, act, cache,
+                                               block_table=bt)
+                new_tok = self._read(new_tok, "decode.wait")
                 steps += 1
-                continue
-            if multi:
-                # one horizon-M round: chained from the device carry when
-                # the host hasn't touched its mirrors since the last round
-                # (zero carry upload in steady state), rebuilt from the
-                # mirrors otherwise
-                src = carry if carry is not None else {
-                    "token": tok, "pos": pos, "active": act,
-                    "remaining": rem}
-                toks_dev, carry, cache = ex.decode_multi(
-                    src["token"], src["pos"], src["active"],
-                    src["remaining"], cache, block_table=bt,
-                    eos_id=self.eos_id)
-                steps += M
-                rounds += 1
-                prev, pending = pending, toks_dev
-                if prev is not None:
-                    # double buffer: the device is already running round
-                    # N+1 while the host replays round N here
-                    process_toks(prev)
-                if not pipeline:
-                    drain()
-                continue
-            new_tok, cache = ex.decode(tok, pos, act, cache, block_table=bt)
-            new_tok = np.asarray(new_tok)
-            host_syncs += 1
-            steps += 1
-            now = clock() - t0
-            for slot in list(sched.running):
-                if not act[slot]:
-                    continue            # chunking slot: masked this step
-                req = sched.running[slot]
-                t = int(new_tok[slot])
-                req.emit(t, now)
-                pos[slot, 0] += 1
-                if t == self.eos_id or len(req.tokens) >= req.max_new:
-                    act[slot] = False
-                    retire(slot, now)
-                else:
-                    tok[slot, 0] = t
+                now = clock() - t0
+                n_retired = 0
+                with TraceAnnotation("decode.emit") as ann:
+                    for slot in list(sched.running):
+                        if not act[slot]:
+                            continue        # chunking slot: masked
+                        req = sched.running[slot]
+                        t = int(new_tok[slot])
+                        req.emit(t, now)
+                        pos[slot, 0] += 1
+                        if t == self.eos_id or len(req.tokens) >= req.max_new:
+                            act[slot] = False
+                            retire(slot, now)
+                            n_retired += 1
+                        else:
+                            tok[slot, 0] = t
+                    ann.set_metadata(n_retired=n_retired)
         drain()      # a trailing all-masked round can still be in flight
         wall = clock() - t0
+        window.__exit__(None, None, None)
         stats = summarize(sched.finished, wall,
                           mode="continuous" if continuous else "static")
         stats["decode_steps"] = steps
@@ -1027,27 +1114,16 @@ class Server:
         # loop paid (decode steps / multi-step rounds / spec draft+verdict
         # pairs / prefill first-token reads).  The per-token ratio is the
         # serve_bench §7 gate: horizon M cuts it ~Mx.
-        stats["host_syncs"] = host_syncs
+        stats["host_syncs"] = self._syncs
         stats["host_syncs_per_token"] = round(
-            host_syncs / max(stats["tokens"], 1), 4)
+            self._syncs / max(stats["tokens"], 1), 4)
+        # the host work that, at horizon 1, lies on the device's critical
+        # path: the window less its blocking reads and arrival sleeps
+        stats["host_busy_s"] = wall - self._wait_s - sleep_s
         stats["loop_iters"] = loop_iters
         stats["decode_horizon"] = self.decode_horizon
         if multi:
             stats["decode_rounds"] = rounds
-        # MFU (the paper's MACs/W figure of merit tied back to measured
-        # throughput).  MACs/token comes from the analytic roofline at the
-        # mean final context; peak is the published bf16 peak of the chip
-        # the mesh runs on times the mesh size — None (not measured) when
-        # the mesh is not on a TPU.
-        fin = sched.finished
-        mean_ctx = (sum(len(r.full_seq) for r in fin) / len(fin)
-                    if fin else 1.0)
-        macs_tok = decode_macs_per_token(self.cfg, int(mean_ctx))
-        n_dev = int(ex.mesh.size)
-        stats["macs_per_token"] = round(macs_tok, 1)
-        peaks = device_peaks(ex.mesh.devices.flat[0])
-        stats["mfu"] = (None if peaks is None else 2.0 * macs_tok
-                        * stats["tok_per_s"] / (peaks.bf16_flops * n_dev))
         if self.spec:
             rounds = int(sum(r.spec_rounds for r in sched.finished))
             accepted = int(sum(r.spec_accepted for r in sched.finished))
@@ -1184,11 +1260,6 @@ def build_server(args) -> Tuple[Server, object]:
                     decode_horizon=int(getattr(args, "decode_horizon", 1)
                                        or 1))
     return server, cfg
-
-
-def format_mfu(mfu) -> str:
-    """The mfu stat for printing: "not measured" off the chip."""
-    return "not measured" if mfu is None else f"{mfu:.2e}"
 
 
 def trace_from_args(args, cfg):
@@ -1381,7 +1452,6 @@ def main():
         print(f"[{mode}] served {stats['n_requests']} requests: "
               f"{stats['tokens']} tokens in {stats['wall_s']:.3f}s = "
               f"{stats['tok_per_s']:.1f} tok/s | "
-              f"mfu {format_mfu(stats['mfu'])} | "
               f"latency p50 {stats['p50_latency_s'] * 1e3:.0f}ms "
               f"p99 {stats['p99_latency_s'] * 1e3:.0f}ms | "
               f"ttft p50 {stats['p50_ttft_s'] * 1e3:.0f}ms | "

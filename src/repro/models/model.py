@@ -173,9 +173,10 @@ class Model:
 
     def _logits(self, params, x):
         cfg = self.cfg
-        if cfg.tie_embeddings:
-            return tied_logits(params["embed"], x, cfg.quant_mode)
-        return linear(params["lm_head"], x, cfg.quant_mode)
+        with jax.named_scope("lm_head"):
+            if cfg.tie_embeddings:
+                return tied_logits(params["embed"], x, cfg.quant_mode)
+            return linear(params["lm_head"], x, cfg.quant_mode)
 
     # ----------------------------------------------------------- full forward
     def forward(self, params, batch, collect_cache=False, pos0=0,

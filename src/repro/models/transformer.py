@@ -52,8 +52,9 @@ def apply_block(p, x, cfg, kind, positions, enc_kv=None, ctx=None):
     h = layers.apply_norm(p["norm1"], x, cfg)
     state = None
     if kind in ("attn", "xattn"):
-        y, (k, v, k_pos) = attention.attention_block(p["attn"], h, cfg,
-                                                     positions, ctx=ctx)
+        with jax.named_scope("attn"):
+            y, (k, v, k_pos) = attention.attention_block(p["attn"], h, cfg,
+                                                         positions, ctx=ctx)
         state = {"k": k, "v": v, "k_pos": k_pos}
         x = x + y
         if kind == "xattn":
@@ -69,11 +70,12 @@ def apply_block(p, x, cfg, kind, positions, enc_kv=None, ctx=None):
         x = x + y
     aux = jnp.zeros((), jnp.float32)
     if kind != "mamba":
-        h2 = layers.apply_norm(p["norm2"], x, cfg)
-        if cfg.family == "moe":
-            y2, aux = moe.moe_ffn(p["moe"], h2, cfg)
-        else:
-            y2 = layers.apply_mlp(p["mlp"], h2, cfg)
+        with jax.named_scope("mlp"):
+            h2 = layers.apply_norm(p["norm2"], x, cfg)
+            if cfg.family == "moe":
+                y2, aux = moe.moe_ffn(p["moe"], h2, cfg)
+            else:
+                y2 = layers.apply_mlp(p["mlp"], h2, cfg)
         x = x + y2
     return x, state, aux
 
@@ -105,15 +107,15 @@ def apply_block_decode(p, x, cfg, kind, positions, cache, enc_kv=None,
     paged layout (configs.ModelConfig.paged_capable)."""
     h = layers.apply_norm(p["norm1"], x, cfg)
     if kind in ("attn", "xattn"):
-        if block_tables is not None:
-            y, cache = attention.paged_decode_attention_block(
-                p["attn"], h, cfg, positions, cache, block_tables,
-                active=active, constrain=constrain)
-        else:
-            y, cache = attention.decode_attention_block(p["attn"], h, cfg,
-                                                        positions, cache,
-                                                        active=active,
-                                                        constrain=constrain)
+        with jax.named_scope("attn"):
+            if block_tables is not None:
+                y, cache = attention.paged_decode_attention_block(
+                    p["attn"], h, cfg, positions, cache, block_tables,
+                    active=active, constrain=constrain)
+            else:
+                y, cache = attention.decode_attention_block(
+                    p["attn"], h, cfg, positions, cache, active=active,
+                    constrain=constrain)
         x = x + y
         if kind == "xattn":
             hx = layers.apply_norm(p["norm_x"], x, cfg)
@@ -132,11 +134,12 @@ def apply_block_decode(p, x, cfg, kind, positions, cache, enc_kv=None,
             cache = constrain(cache)
         x = x + y
     if kind != "mamba":
-        h2 = layers.apply_norm(p["norm2"], x, cfg)
-        if cfg.family == "moe":
-            y2, _ = moe.moe_ffn(p["moe"], h2, cfg)
-        else:
-            y2 = layers.apply_mlp(p["mlp"], h2, cfg)
+        with jax.named_scope("mlp"):
+            h2 = layers.apply_norm(p["norm2"], x, cfg)
+            if cfg.family == "moe":
+                y2, _ = moe.moe_ffn(p["moe"], h2, cfg)
+            else:
+                y2 = layers.apply_mlp(p["mlp"], h2, cfg)
         x = x + y2
     return x, cache
 
@@ -303,15 +306,17 @@ def apply_block_verify(p, x, cfg, positions, cache, block_tables,
     attention half goes through ``attention.paged_verify_attention_block``;
     norms and the FFN half are shape-generic over (B, k, d)."""
     h = layers.apply_norm(p["norm1"], x, cfg)
-    y, cache = attention.paged_verify_attention_block(
-        p["attn"], h, cfg, positions, cache, block_tables,
-        active=active, constrain=constrain)
+    with jax.named_scope("attn"):
+        y, cache = attention.paged_verify_attention_block(
+            p["attn"], h, cfg, positions, cache, block_tables,
+            active=active, constrain=constrain)
     x = x + y
-    h2 = layers.apply_norm(p["norm2"], x, cfg)
-    if cfg.family == "moe":
-        y2, _ = moe.moe_ffn(p["moe"], h2, cfg)
-    else:
-        y2 = layers.apply_mlp(p["mlp"], h2, cfg)
+    with jax.named_scope("mlp"):
+        h2 = layers.apply_norm(p["norm2"], x, cfg)
+        if cfg.family == "moe":
+            y2, _ = moe.moe_ffn(p["moe"], h2, cfg)
+        else:
+            y2 = layers.apply_mlp(p["mlp"], h2, cfg)
     return x + y2, cache
 
 

@@ -174,18 +174,6 @@ def forward_flops(cfg, B, S, kind):
     return total
 
 
-def decode_macs_per_token(cfg, ctx_len: int) -> float:
-    """Roofline MACs to emit ONE token at context length ``ctx_len`` —
-    the per-token work term of the paper's MACs/W figure of merit, and the
-    numerator of the serve stats' MFU:
-
-        MFU = macs*2 * tok_per_s / (device_peaks(dev).bf16_flops * n_devices)
-
-    One decode step for one slot is ``forward_flops(cfg, B=1, S=ctx,
-    kind="decode")``; a MAC is 2 FLOPs."""
-    return forward_flops(cfg, 1, max(int(ctx_len), 1), "decode") / 2.0
-
-
 def _layer_kind_list(cfg):
     from repro.models.transformer import layer_kinds
     return layer_kinds(cfg)

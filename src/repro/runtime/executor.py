@@ -60,6 +60,12 @@ def single_device_mesh():
     return jax.sharding.Mesh(dev, ("data", "model"))
 
 
+def _set_table_row(table, slot, row):
+    """One slot's row of the device block table replaced; jitted by name,
+    so its program reads ``jit__set_table_row`` in a device trace."""
+    return table.at[slot].set(row)
+
+
 class DeviceBlockTable:
     """Host-mirrored, device-resident block table (DESIGN.md §3 "Multi-step
     decode & host overlap").
@@ -340,7 +346,7 @@ class Executor:
             # row per dirty slot.  Not donated — a pipelined in-flight
             # round may still hold the previous table array as an input.
             self._bt_set_row = jax.jit(
-                lambda t, s, row: t.at[s].set(row),
+                _set_table_row,
                 out_shardings=self._step_shardings["block_table"])
 
         if self.decode_horizon > 1:

@@ -11,7 +11,6 @@ import jax
 import pytest
 
 from repro.launch import compile_cache
-from repro.launch.serve import format_mfu
 from repro.perf.roofline_model import DEVICE_PEAKS, device_peaks
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -34,8 +33,6 @@ class TestDevicePeaks:
 
     def test_cpu_is_not_measured(self):
         assert device_peaks(jax.devices()[0]) is None
-        assert format_mfu(None) == "not measured"
-        assert format_mfu(0.25) == "2.50e-01"
 
 
 class TestCompileCache:
