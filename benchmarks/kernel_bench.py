@@ -17,10 +17,17 @@ oracle-vs-gather agreement, and (with ``--kernel-check``, the CI
 kernel-bench leg) the interpret-mode Pallas kernel's max error against the
 oracle.  ``python -m benchmarks.kernel_bench --out BENCH_kernel.json``
 writes the machine-readable artifact CI asserts on.
+
+``--tile-sweep OUT.json`` runs on a TPU only: it times the int8 body of
+``psi_matmul`` at qwen3-8b's decode GEMMs (M = 32 slots, bf16) for each
+legal (bn, bk) and prints microseconds per call and the share of the HBM
+floor (the codes' bytes at the chip's bandwidth) — the measurement
+``psi_matmul.pick_tiles`` was fixed from.  Without a TPU it exits 2.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
@@ -186,6 +193,83 @@ def run():
     return rows
 
 
+# qwen3-8b's decode GEMMs (K, N): wq/wo, wk/wv, w_gate/w_up, w_down, lm_head
+SWEEP_SHAPES = [(4096, 4096), (4096, 1024), (4096, 12288), (12288, 4096),
+                (4096, 151936)]
+SWEEP_BN = (128, 256, 512, 1024, 2048)
+SWEEP_BK = (512, 1024, 2048, 4096, 12288)
+HBM_BYTES_PER_S = 819e9          # TPU v5e (Google Cloud, "TPU v5e")
+
+
+def _us_per_call(fn, args, reps):
+    """Device microseconds per call of ``fn(x, *rest)``: ``reps`` calls
+    chained in one program (each call's x depends on the last output, so
+    none is hoisted), less the same loop around an empty body."""
+    def looped(body):
+        def run(x, *rest):
+            def step(_, s):
+                return body(x + s.astype(x.dtype), *rest) * 1e-30
+            return jax.lax.fori_loop(0, reps, step, jnp.float32(0))
+        return jax.jit(run)
+
+    def best(f):
+        jax.block_until_ready(f(*args))
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(*args))
+            times.append(time.perf_counter() - t0)
+        return min(times)
+
+    full = looped(lambda *a: fn(*a)[0, 0].astype(jnp.float32))
+    empty = looped(lambda xi, *_: jnp.sum(xi, dtype=jnp.float32))
+    return (best(full) - best(empty)) / reps * 1e6
+
+
+def tile_sweep(M=32, dtype=jnp.bfloat16):
+    """(bn, bk) sweep of the int8 body at the decode shapes; on a TPU."""
+    if jax.default_backend() != "tpu":
+        raise SystemExit(2)
+    itemsize = jnp.dtype(dtype).itemsize
+    bm = pk.pick_bm(M, dtype)
+    rng = np.random.default_rng(0)
+    records = []
+    for K, N in SWEEP_SHAPES:
+        x = jnp.asarray(rng.normal(size=(M, K)), dtype)
+        codes = jnp.asarray(rng.integers(-127, 128, size=(K, N),
+                                         dtype=np.int8))
+        scale = jnp.asarray(rng.uniform(1e-3, 1e-2, size=(N,)), jnp.float32)
+        floor_us = K * N / HBM_BYTES_PER_S * 1e6
+        picked = pk.pick_tiles(M, K, N, dtype)
+        reps = max(20, min(400, int(20000 / floor_us)))
+        tiles = [(bm, 128, 128), picked] + [
+            (bm, bn, bk) for bn in SWEEP_BN for bk in SWEEP_BK
+            if bn <= N and K % bk == 0
+            and pk._int8_vmem_bytes(bm, bn, bk, itemsize) <= pk._SCOPED_VMEM]
+        want = np.asarray(ref.psi_matmul_int8_ref(x, codes, scale),
+                          np.float32)
+        for tile in dict.fromkeys(tiles):
+            fn = functools.partial(pk.psi_matmul_codes, bm=tile[0],
+                                   bn=tile[1], bk=tile[2])
+            rec = {"K": K, "N": N, "bm": tile[0], "bn": tile[1],
+                   "bk": tile[2], "picked": tile == picked,
+                   "floor_us": round(floor_us, 2)}
+            try:
+                us = _us_per_call(fn, (x, codes, scale), reps)
+                got = np.asarray(fn(x, codes, scale), np.float32)
+                rec.update(us=round(us, 2),
+                           floor_pct=round(100 * floor_us / us, 1),
+                           max_err_vs_ref=float(np.abs(got - want).max()))
+            except Exception as e:               # noqa: BLE001 — recorded
+                rec["error"] = str(e).splitlines()[0][:160]
+            records.append(rec)
+            print(json.dumps(rec), flush=True)
+    dev = jax.devices()[0]
+    return {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": jax.device_count()},
+            "M": M, "dtype": jnp.dtype(dtype).name, "rows": records}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default=None,
@@ -194,7 +278,16 @@ def main(argv=None):
     ap.add_argument("--kernel-check", action="store_true",
                     help="also run the interpret-mode Pallas kernel against "
                          "the oracle on the bounded-size configs")
+    ap.add_argument("--tile-sweep", default=None, metavar="OUT.json",
+                    help="on a TPU: time psi_matmul's int8 body per (bn, bk) "
+                         "at qwen3-8b's decode GEMMs and write the table")
     args = ap.parse_args(argv)
+    if args.tile_sweep is not None:
+        table = tile_sweep()
+        with open(args.tile_sweep, "w") as f:
+            json.dump(table, f, indent=1)
+        print(f"wrote {args.tile_sweep}: {len(table['rows'])} tiles")
+        return
     if args.out is None:
         run()
         return

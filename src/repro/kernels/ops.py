@@ -20,10 +20,12 @@ Set ``REPRO_FORCE_INTERPRET=1`` to route through
 ``pallas_call(interpret=True)`` on CPU (used by kernel tests).  On a TPU
 backend it is an error: the interpreter would stand in for the chip.
 
-Decode-shaped dispatch (DESIGN.md §2): the M-tile follows the actual row
-count (``psi_matmul.pick_bm``), so a decode step over <=16 slots stops
-padding M up to the 128-row MXU tile (8-16x fewer padded MACs per GEMV;
-tracked by ``benchmarks/kernel_bench.py``).
+Decode-shaped dispatch (DESIGN.md §2): the tiles follow the call's shape
+(``psi_matmul.pick_tiles``).  A decode step (M = active slots, below 128)
+gets an M-tile that covers its rows (``pick_bm``) and weight tiles of about
+1 MiB, so each grid step streams a slab of codes instead of 16 KiB; prefill
+pieces (M >= 128) keep 128 x 128 x 128.  The packed sub-byte body keeps
+128 x 128 weight tiles with the M-tile from ``pick_bm``.
 
 :func:`paged_decode_attention` applies the same contract to the paged
 decode read side (DESIGN.md §3 "Paged-decode kernel"): tpu -> the fused
@@ -105,15 +107,17 @@ def psi_matmul_2d(x2d: jnp.ndarray, qt: psi.QuantizedTensor) -> jnp.ndarray:
     """(M, K) x QuantizedTensor weight -> (M, N) on one device."""
     route = matmul_route()
     scale = qt.scale.reshape(-1)
-    bm = _pk.pick_bm(x2d.shape[0], x2d.dtype)
+    M, K = x2d.shape
     if route in ("pallas", "interpret"):
         interp = route == "interpret"
         if qt.packed:
             return _pk.psi_matmul_packed(x2d, qt.data, scale,
-                                         bits=qt.fmt.bits, bm=bm,
+                                         bits=qt.fmt.bits,
+                                         bm=_pk.pick_bm(M, x2d.dtype),
                                          interpret=interp)
-        return _pk.psi_matmul_codes(x2d, qt.data, scale, bm=bm,
-                                    interpret=interp)
+        bm, bn, bk = _pk.pick_tiles(M, K, qt.data.shape[-1], x2d.dtype)
+        return _pk.psi_matmul_codes(x2d, qt.data, scale, bm=bm, bn=bn,
+                                    bk=bk, interpret=interp)
     if route == "gpu":
         if qt.packed:
             return _ref.psi_matmul_packed_dequant(x2d, qt.data, scale,

@@ -18,10 +18,16 @@ Layout / tiling:
     barrel-shifter mirror), subtracts 2^(bits-1), converts, dots.  One kernel
     body serves every sub-byte width in the PsiFormat registry — ``bits`` is
     a static argument baked per format at trace time.
-  * bm/bn/bk default 128/128/128 — MXU-aligned (multiples of 128 on the
-    matmul dims), VMEM footprint per step ~ bm*bk*2 + bk*bn + bm*bn*4
-    ≈ 128 KiB, far under the ~16 MiB/core budget, leaving room for
-    double-buffered pipelining by the Mosaic compiler.
+  * Tiles follow the call's shape (:func:`pick_tiles`).  Prefill pieces
+    (M >= 128) keep 128/128/128, MXU-aligned.  Serving reads the whole
+    weight once per decode step for a few rows of x, so a decode-shaped
+    call (M < 128) takes ``bm`` = M rounded up to the sublane tile and a
+    weight tile of about 1 MiB (``bn`` up to 1024 and a ``bk`` that
+    divides K; 1024 x 1024 at qwen3-8b's widths): per step ~ 2*bk*bn
+    int8 + bk*bn converted + a few x/out/accumulator tiles, about 4.5 MiB
+    of VMEM in bf16, within the default scoped limit.  The int8 body pads
+    no codes where ``bk`` divides K: N runs over ``cdiv(N, bn)`` blocks
+    with a ragged last one.  The packed body keeps 128 x 128 weight tiles.
 
 Validated on CPU with ``interpret=True`` against ``repro.kernels.ref``.
 """
@@ -45,17 +51,74 @@ _MIN_SUBLANE = {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.float32): 8}
 def pick_bm(M: int, dtype=jnp.float32) -> int:
     """Decode-shaped M-tile dispatch (DESIGN.md §2).
 
-    The serving decode step calls the kernel at M = active slots (1-16);
-    padding M up to the square 128-row tile makes the MXU grind 8-16x
-    zero rows per (n, k) grid step.  Pick the smallest legal sublane
-    multiple covering M instead (f32: 8, bf16: 16) — same kernel body,
-    same numerics (the block-shape-sweep tests assert invariance), just a
-    shorter M tile.  Large M keeps the square MXU-aligned default.
+    The serving decode step calls the kernel at M = decode slots (32 in
+    the chip benchmark); padding M up to the square 128-row tile makes the
+    MXU grind 4-128x zero rows per (n, k) grid step.  Pick the smallest
+    legal sublane multiple covering M instead (f32: 8, bf16: 16) — same
+    kernel body, same numerics (the block-shape-sweep tests assert
+    invariance), just a shorter M tile.  Large M keeps the square
+    MXU-aligned default.
     """
     if M >= DEFAULT_BM:
         return DEFAULT_BM
     lo = _MIN_SUBLANE.get(jnp.dtype(dtype), 8)
     return max(lo, -(-M // lo) * lo)
+
+
+# Scoped VMEM a kernel may use without raising its limit (the v5e default).
+_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _int8_vmem_bytes(bm: int, bn: int, bk: int, itemsize: int) -> int:
+    """VMEM one grid step of the int8 body holds: the double-buffered code,
+    x, scale and output tiles, the code tile converted to the activation
+    dtype, and the f32 accumulator."""
+    return (2 * bk * bn + bk * bn * itemsize + 2 * bm * bk * itemsize
+            + 2 * bm * bn * itemsize + 2 * 8 * bn * 4 + bm * bn * 4)
+
+
+# Decode-shaped tiles (M < 128): the widest N block, and the int8 code tile
+# (bk * bn bytes) each grid step streams.  On a v5e a grid step costs about
+# 0.26 us besides its DMA, so 16 KiB tiles (128 x 128) reach 8% of the HBM
+# floor; 1 MiB tiles reach 80-91% at every qwen3-8b decode GEMM, 2 MiB
+# ones no more (``benchmarks/kernel_bench.py --tile-sweep``, PERF.md).
+_DECODE_BN = 1024
+_DECODE_TILE_BYTES = 2 ** 20
+
+
+def _decode_bn(N: int) -> int:
+    """N block for a decode-shaped call: all of N when it fits one block,
+    else the widest multiple of 128 up to ``_DECODE_BN`` that divides N, or
+    ``_DECODE_BN`` with a ragged last block where only narrow ones divide
+    (151936 = 128 * 1187, 75968 = 64 * 1187)."""
+    if N <= _DECODE_BN:
+        return N
+    div = max((b for b in range(128, _DECODE_BN + 1, 128) if N % b == 0),
+              default=0)
+    return div if 2 * div >= _DECODE_BN else _DECODE_BN
+
+
+def pick_tiles(M: int, K: int, N: int, dtype=jnp.float32):
+    """(bm, bn, bk) of the int8 body for an (M, K) x (K, N) call.
+
+    M >= 128 (prefill pieces) keeps the square 128 x 128 x 128 tiles.  A
+    decode-shaped call (M < 128) has little to multiply and a whole weight
+    to read, so each grid step streams a slab of codes: ``bm`` covers M
+    (:func:`pick_bm`), ``bn`` is :func:`_decode_bn`, and ``bk`` is the
+    deepest divisor of K (a multiple of 128, or K itself) whose code tile
+    stays within ``_DECODE_TILE_BYTES`` and whose footprint fits scoped
+    VMEM.  ``bk`` divides K so the codes are never padded; where K has no
+    such divisor it falls back to 128 (K padded).
+    """
+    bm = pick_bm(M, dtype)
+    if M >= DEFAULT_BM:
+        return bm, DEFAULT_BN, DEFAULT_BK
+    bn = _decode_bn(N)
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [d for d in [*range(128, K, 128), K]
+            if K % d == 0 and d * bn <= _DECODE_TILE_BYTES
+            and _int8_vmem_bytes(bm, bn, d, itemsize) <= _SCOPED_VMEM]
+    return bm, bn, max(fits, default=DEFAULT_BK)
 
 
 def padded_macs(M: int, K: int, N: int, *, bm: int = DEFAULT_BM,
@@ -133,17 +196,22 @@ def psi_matmul_int8(x, codes, scale, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
 
     Serves every *unpacked* PsiFormat — sub-byte codes are stored int8, so
     the kernel body is width-independent (``psi_matmul_codes`` is the
-    format-neutral alias ``repro.kernels.ops`` dispatches through)."""
+    format-neutral alias ``repro.kernels.ops`` dispatches through).
+
+    The codes are not padded on N: it runs over ``cdiv(N, bn)`` blocks,
+    the last one ragged (its out-of-range columns are computed from
+    whatever the block holds and dropped on write; columns never mix).  K
+    is padded only when ``bk`` does not divide it, since padded rows enter
+    the sum — :func:`pick_tiles` picks a ``bk`` that divides K."""
     M, K = x.shape
     Kc, N = codes.shape
     assert K == Kc
+    bn = min(bn, N)                     # a block as wide as N is always legal
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
-    cp = _pad_to(_pad_to(codes, bk, 0), bn, 1)
-    sp = _pad_to(scale.reshape(1, -1), bn, 1)
+    cp = _pad_to(codes, bk, 0)
     Mp, Kp = xp.shape
-    _, Np = cp.shape
     k_steps = Kp // bk
-    grid = (Mp // bm, Np // bn, k_steps)
+    grid = (Mp // bm, pl.cdiv(N, bn), k_steps)
     out = pl.pallas_call(
         functools.partial(_int8_kernel, k_steps=k_steps),
         grid=grid,
@@ -153,13 +221,13 @@ def psi_matmul_int8(x, codes, scale, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
             pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xp, cp, sp)
-    return out[:M, :N]
+    )(xp, cp, scale.reshape(1, -1))
+    return out[:M]
 
 
 # Format-neutral alias: any registered width's unpacked codes are int8.

@@ -14,6 +14,28 @@ def _quant(w, bits):
     return q.codes, q.scale.reshape(-1)
 
 
+# qwen3-8b's decode GEMMs (K, N): wq/wo, wk/wv, w_gate/w_up, w_down,
+# lm_head; and their local N shards on a 2x2 mesh (N over "model").
+QWEN3_DECODE_GEMMS = [(4096, 4096), (4096, 1024), (4096, 12288),
+                      (12288, 4096), (4096, 151936)]
+QWEN3_2X2_SHARDS = [(K, N // 2) for K, N in QWEN3_DECODE_GEMMS]
+
+
+def _int8_pads(jaxpr):
+    """``pad`` equations on int8 operands anywhere in a (closed) jaxpr."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pad" and \
+                eqn.invars[0].aval.dtype == jnp.int8:
+            found.append(eqn)
+        for v in eqn.params.values():
+            if hasattr(v, "eqns") or hasattr(getattr(v, "jaxpr", None),
+                                             "eqns"):
+                found += _int8_pads(v)
+    return found
+
+
 SHAPES = [
     (8, 16, 8),          # tiny (full padding path)
     (128, 128, 128),     # exactly one tile
@@ -127,11 +149,94 @@ class TestDecodeDispatch:
         x = jnp.asarray(rng.normal(size=(M, 256)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(256, 192)).astype(np.float32))
         codes, scale = _quant(w, 8)
-        bm = pk.pick_bm(M, x.dtype)
-        got = pk.psi_matmul_int8(x, codes, scale, bm=bm, interpret=True)
+        bm, bn, bk = pk.pick_tiles(M, 256, 192, x.dtype)
+        got = pk.psi_matmul_int8(x, codes, scale, bm=bm, bn=bn, bk=bk,
+                                 interpret=True)
         want = ref.psi_matmul_int8_ref(x, codes, scale)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("K,N", QWEN3_DECODE_GEMMS + QWEN3_2X2_SHARDS)
+    def test_pick_tiles_never_pads_the_weight(self, K, N):
+        """At every qwen3-8b decode GEMM, one chip's and a 2x2 mesh's local
+        N shard, the decode tiles divide K exactly and N either exactly or
+        through the ragged last block; each streams a slab of codes well
+        inside scoped VMEM."""
+        bm, bn, bk = pk.pick_tiles(32, K, N, jnp.bfloat16)
+        assert bm == 32
+        assert K % bk == 0 and bk % 128 == 0
+        assert bn % 128 == 0 and bn <= N
+        assert 512 * 1024 <= bk * bn <= pk._DECODE_TILE_BYTES
+        assert pk._int8_vmem_bytes(bm, bn, bk, 2) <= pk._SCOPED_VMEM
+        jaxpr = jax.make_jaxpr(
+            lambda x, c, s: pk.psi_matmul_int8(x, c, s, bm=bm, bn=bn,
+                                               bk=bk))(
+            jax.ShapeDtypeStruct((32, K), jnp.bfloat16),
+            jax.ShapeDtypeStruct((K, N), jnp.int8),
+            jax.ShapeDtypeStruct((N,), jnp.float32))
+        assert _int8_pads(jaxpr) == []
+
+    def test_untiled_k_pads_the_codes(self):
+        """The jaxpr check above sees a pad where there is one: a bk that
+        does not divide K pads the codes on K."""
+        jaxpr = jax.make_jaxpr(
+            lambda x, c, s: pk.psi_matmul_int8(x, c, s, bm=32, bn=128,
+                                               bk=128))(
+            jax.ShapeDtypeStruct((32, 200), jnp.bfloat16),
+            jax.ShapeDtypeStruct((200, 256), jnp.int8),
+            jax.ShapeDtypeStruct((256,), jnp.float32))
+        assert len(_int8_pads(jaxpr)) == 1
+
+    def test_pick_tiles_lm_head_takes_the_ragged_path(self):
+        # 151936 = 128 * 1187 (prime): only 128-wide blocks divide it
+        assert pk.pick_tiles(32, 4096, 151936, jnp.bfloat16) == \
+            (32, 1024, 1024)
+        assert pk.pick_tiles(32, 4096, 75968, jnp.bfloat16) == \
+            (32, 1024, 1024)
+
+    @pytest.mark.parametrize("M", [128, 512, 4096])
+    @pytest.mark.parametrize("K,N", QWEN3_DECODE_GEMMS)
+    def test_pick_tiles_prefill_keeps_square_tiles(self, M, K, N):
+        assert pk.pick_tiles(M, K, N, jnp.bfloat16) == (128, 128, 128)
+
+    def test_packed_body_keeps_its_tiles(self, monkeypatch):
+        """The packed sub-byte body is dispatched with the M-tile alone:
+        its weight tiles stay at 128 x 128."""
+        seen = {}
+
+        def spy(x, planes, scale, **kw):
+            seen.update(kw)
+            return ref.psi_matmul_packed_ref(x, planes, scale, kw["bits"])
+
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+        monkeypatch.setattr(ops._pk, "psi_matmul_packed", spy)
+        rng = np.random.default_rng(13)
+        x = jnp.asarray(rng.normal(size=(32, 256)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(256, 1024)).astype(np.float32))
+        ops.psi_matmul(x, psi.quantize_weights(w, 5, axis=0).pack())
+        assert seen == {"bits": 5, "bm": 32, "interpret": True}
+        assert (pk.DEFAULT_BN, pk.DEFAULT_BK) == (128, 128)
+
+    @pytest.mark.parametrize("N", [1024, 1408], ids=["exact", "ragged"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_picked_decode_tiles_match_ref(self, N, dtype):
+        """The decode tiles at a reduced width: 1024 x 1024 code slabs, two
+        K steps, and (N = 1408) a ragged last N block of 384 columns."""
+        M, K = 32, 2048
+        rng = np.random.default_rng(N)
+        x = jnp.asarray(rng.normal(size=(M, K)), dtype)
+        w = jnp.asarray(rng.normal(size=(K, N)).astype(np.float32))
+        codes, scale = _quant(w, 8)
+        bm, bn, bk = pk.pick_tiles(M, K, N, dtype)
+        assert (bn, bk) == (1024, 1024)
+        got = pk.psi_matmul_int8(x, codes, scale, bm=bm, bn=bn, bk=bk,
+                                 interpret=True)
+        want = ref.psi_matmul_int8_ref(x, codes, scale)
+        assert got.shape == (M, N)
+        tol = dict(rtol=1e-5, atol=1e-4) if dtype == jnp.float32 \
+            else dict(rtol=2e-2, atol=2e-2)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **tol)
 
 
 class TestGpuFastPath:

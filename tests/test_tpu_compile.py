@@ -24,6 +24,7 @@ from repro.models import build_model
 
 D, FF = 4096, 12288                        # qwen3-8b widths
 HQ, HKV, HD = 32, 8, 128
+VOCAB = 151936
 HBM_BYTES = 16e9                          # one v5e chip
 
 
@@ -90,6 +91,69 @@ def test_psi_matmul_codes_compiles(one_chip, M, bm):
     scale = _sds((FF,), jnp.float32, one_chip)
     compiled = pk.psi_matmul_codes.lower(x, codes, scale, bm=bm).compile()
     assert _has_kernel(compiled)
+
+
+def _custom_call_names(compiled):
+    """The kernels' op names as the device trace gives them (the HLO text
+    of each custom call, ``chipbench/readers.py`` matches its head)."""
+    import re
+    lines = (l.strip().removeprefix("ROOT ")
+             for l in compiled.as_text().splitlines())
+    return [re.match(r"%[\w.]+ = ", l).group(0) for l in lines
+            if "custom-call(" in l and re.match(r"%[\w.]+ = ", l)]
+
+
+def _int8_rewrites(compiled):
+    """Instructions that make a new int8 array (a pad, copy or slice of the
+    codes): a per-call copy of the weight."""
+    import re
+    return [l.strip() for l in compiled.as_text().splitlines()
+            if re.search(r"= s8\[[\d,]*\]\S* (?!parameter)", l.strip()
+                         .removeprefix("ROOT "))]
+
+
+@pytest.mark.parametrize("K,N", [(D, D), (D, HKV * HD), (D, FF), (FF, D),
+                                 (D, VOCAB)],
+                         ids=["wq_wo", "wk_wv", "w_gate_up", "w_down",
+                              "lm_head"])
+def test_psi_matmul_decode_tiles_compile(one_chip, K, N):
+    """The int8 body at each qwen3-8b decode GEMM (32 slots, bf16) with
+    the tiles ``pick_tiles`` gives: it fits scoped VMEM, keeps the op name
+    the benchmark's readers match, and neither pads nor copies the codes
+    (lm_head's N = 151936 takes a ragged last block)."""
+    import re
+    bm, bn, bk = pk.pick_tiles(32, K, N, jnp.bfloat16)
+    x = _sds((32, K), jnp.bfloat16, one_chip)
+    codes = _sds((K, N), jnp.int8, one_chip)
+    scale = _sds((N,), jnp.float32, one_chip)
+    compiled = pk.psi_matmul_codes.lower(x, codes, scale, bm=bm, bn=bn,
+                                         bk=bk).compile()
+    names = _custom_call_names(compiled)
+    assert len(names) == 1
+    assert re.match(r"^%psi_matmul_(int8|packed)[.\d]* = ", names[0])
+    assert _int8_rewrites(compiled) == []
+    assert pk._int8_vmem_bytes(bm, bn, bk, 2) <= pk._SCOPED_VMEM
+
+
+def test_sharded_lm_head_compiles_without_weight_pad(mesh, on_tpu):
+    """On a 2x2 mesh each device's lm_head shard is 75,968 columns, not a
+    multiple of 128: the ragged last block serves it without padding the
+    codes.  (XLA lays such a parameter out column-major and copies it to
+    the kernel's row-major layout; that copy is the layout's, not the
+    tiling's.)"""
+    x = _sds((32, D), jnp.bfloat16, NamedSharding(mesh, P("data", None)))
+    codes = _sds((D, VOCAB), jnp.int8, NamedSharding(mesh, P(None, "model")))
+    scale = _sds((1, VOCAB), jnp.float32,
+                 NamedSharding(mesh, P(None, "model")))
+    fmt = psi.get_format(8)
+
+    def f(x, data, scale):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return ops.psi_matmul(x, psi.QuantizedTensor(data, scale, fmt))
+
+    compiled = jax.jit(f).lower(x, codes, scale).compile()
+    assert _has_kernel(compiled)
+    assert not [r for r in _int8_rewrites(compiled) if " pad(" in r]
 
 
 def test_psi_matmul_packed_compiles(one_chip):
